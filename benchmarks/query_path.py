@@ -85,6 +85,7 @@ import jax.numpy as jnp
 from repro.core import FCVIConfig, build, fcvi
 from repro.core.filters import F, compile_predicate
 from repro.data.synthetic import CorpusSpec, make_corpus, sample_queries
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.serve.engine import EngineConfig, FCVIEngine
 
@@ -221,6 +222,7 @@ def main():
                     "scratch path so the committed artifact keeps the full-"
                     "config numbers)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     spec = CorpusSpec(n=args.n, d=args.d, n_categories=6, n_numeric=2, seed=0)
     corpus = make_corpus(spec)

@@ -50,6 +50,7 @@ from repro.index import ivf as ivf_mod
 from repro.index import pq as pq_mod
 from repro.index.backend import SearchBackend
 from repro.kernels import ops
+from repro.kernels import ref
 
 Array = jax.Array
 
@@ -133,9 +134,9 @@ class FCVIIndex:
 
 
 def cosine_sim(a: Array, b: Array, eps: float = 1e-8) -> Array:
-    num = jnp.sum(a * b, axis=-1)
-    den = jnp.linalg.norm(a, axis=-1) * jnp.linalg.norm(b, axis=-1) + eps
-    return num / den
+    """cos(a, b) over the last axis, in the re-rank's fixed order
+    (``ref.cosine``)."""
+    return ref.cosine(a, b, eps)
 
 
 def build(vectors: Array, filters: Array, config: FCVIConfig,
@@ -150,14 +151,21 @@ def build(vectors: Array, filters: Array, config: FCVIConfig,
         vectors, filters, alpha, config.mode,
         n_clusters=config.n_clusters, rng=rng, normalize=config.normalize,
     )
-    vn = tfm.vec_norm.apply(vectors)
-    fn = tfm.filt_norm.apply(filters)
-    transformed = tfm.apply_normalized(vn, fn)
-
+    vn, fn, transformed = _normalize_and_transform(tfm, vectors, filters)
     backend = build_backend(transformed, config, rng=rng)
     assert isinstance(backend, SearchBackend)
     return FCVIIndex(config=config, transform=tfm, backend=backend,
                      vectors_n=vn, filters_n=fn)
+
+
+@jax.jit
+def _normalize_and_transform(tfm: Transform, vectors: Array, filters: Array):
+    """(normalized vectors, normalized filters, transformed corpus) as one
+    fused pass: op by op, each (n, d) intermediate would be materialized
+    beside the corpus, which is what bounds the corpus a chip can build."""
+    vn = tfm.vec_norm.apply(vectors)
+    fn = tfm.filt_norm.apply(filters)
+    return vn, fn, tfm.apply_normalized(vn, fn)
 
 
 def build_backend(transformed: Array, config: FCVIConfig,
@@ -204,16 +212,16 @@ def combined_score(cand_v: Array, cand_f: Array, qn: Array, fqn: Array,
     if not use_pallas:
         # Bit-stability contract: every serving path (single-device gather,
         # one-hot psum gather, shard-local gather in the gather-free step)
-        # must feed this function GATHER-PRODUCED candidate tiles.  The
-        # elementwise mul+sum cosine reduces each row independently, so a
-        # candidate scores to the same bits regardless of its k-position in
-        # the tile — unlike a dot_general contraction, whose CPU lowering
-        # handles main-loop vs remainder k-rows differently.  Gather outputs
-        # are materialized, so the reduction cannot fuse into a
-        # path-dependent producer and reorder the sum.
-        s_v = cosine_sim(cand_v, qn[:, None, :])
-        s_f = cosine_sim(cand_f, fqn[:, None, :])
-        return lam * s_v + (1.0 - lam) * s_f
+        # must score a candidate to the same bits.  The elementwise mul+sum
+        # cosine reduces each row independently, so a candidate's score does
+        # not depend on its k-position in the tile — unlike a dot_general
+        # contraction, whose CPU lowering handles main-loop vs remainder
+        # k-rows differently. Each row sums in one fixed order
+        # (``ref.lane_sum``), whatever layout its producer (a gather on one
+        # path, an all-reduce on another) leaves it in, and no product is
+        # fused into a multiply-add (``ref.rounded``), whatever the batch
+        # shape. The Pallas kernel computes the same ``ref.ref_rescore``.
+        return ref.ref_rescore(cand_v, cand_f, qn, fqn, lam)
     b = cand_v.shape[0]
     bb = min(8, b)
     s = ops.rescore(_pad_rows(cand_v, bb), _pad_rows(cand_f, bb),

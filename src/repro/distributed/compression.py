@@ -18,7 +18,6 @@ import jax.numpy as jnp
 
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 Array = jax.Array
 
@@ -86,7 +85,7 @@ def cross_pod_grad_sync(mesh: Mesh, pod_axis: str = "pod"):
             deq = dequantize_int8(codes, scales, pad, gl.shape, gl.dtype)
             return jax.lax.psum(deq, pod_axis)
 
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
             check_vma=False)(g, rng)
 

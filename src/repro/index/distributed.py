@@ -20,7 +20,6 @@ import jax.numpy as jnp
 
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
 from repro.core.clustering import assign
 from repro.index import flat as flat_mod
 
@@ -178,7 +177,7 @@ def sharded_search_fn(mesh: Mesh, shard_axes: Sequence[str], k: int,
         stride = n_local
         for ax in reversed(axes):
             offset = offset + jax.lax.axis_index(ax) * stride
-            stride = stride * axis_size(ax)
+            stride = stride * jax.lax.axis_size(ax)
         vals, idx = _local_search(vectors, sq_norms, queries, kl, offset)
         # pad so merges are static even when shards are small
         if vals.shape[-1] < kl:
@@ -192,7 +191,7 @@ def sharded_search_fn(mesh: Mesh, shard_axes: Sequence[str], k: int,
         return vals, idx
 
     row_spec = P(axes)  # rows sharded over the product of axes
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(row_spec, row_spec, P()),
@@ -345,7 +344,7 @@ def routed_search_fn(mesh: Mesh, shard_axes: Sequence[str], k: int,
     in_specs = (P(axes), P(axes), P(), P())
     if degraded:
         in_specs = in_specs + (P(),)
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=in_specs,

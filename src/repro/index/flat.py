@@ -75,12 +75,6 @@ class FlatIndex:
         """SearchBackend protocol entry point."""
         return search(self, queries, k, use_pallas=use_pallas, **opts)
 
-    def search_rows(self, queries: Array, k: int, payload_v: Array,
-                    payload_f: Array, *, use_pallas: bool = False, **opts):
-        """Gather-free SearchBackend entry point (rows, not just ids)."""
-        return search_rows(self, queries, k, payload_v, payload_f,
-                           use_pallas=use_pallas, **opts)
-
     def slab(self):
         """The serving-layout view of this index (see ``repro.index.slab``):
         what the mesh-sharding and checkpoint layers consume."""
@@ -105,8 +99,13 @@ def build(vectors: Array, storage_dtype=None) -> FlatIndex:
                          scales=scales)
     if storage_dtype is not None:
         vectors = vectors.astype(storage_dtype)
-    sq_norms = jnp.sum(vectors.astype(jnp.float32) ** 2, axis=-1)
-    return FlatIndex(vectors=vectors, sq_norms=sq_norms)
+    return FlatIndex(vectors=vectors, sq_norms=row_sq_norms(vectors))
+
+
+@jax.jit
+def row_sq_norms(vectors: Array) -> Array:
+    """fp32 squared row norms in one fused pass (no (n, d) temporary)."""
+    return jnp.sum(vectors.astype(jnp.float32) ** 2, axis=-1)
 
 
 def merge_topk(vals_a: Array, idx_a: Array, vals_b: Array, idx_b: Array, k: int):
@@ -152,14 +151,6 @@ def _exact_refine(vectors: Array, queries: Array, cand_idx: Array, k: int,
         d2 = jnp.where(mask[cand_idx], d2, jnp.inf)
     vals, pos = jax.lax.top_k(-d2, k)
     return vals, jnp.take_along_axis(cand_idx, pos, axis=-1)
-
-
-def _refine_carried(scan_rows: Array, queries: Array, k: int):
-    """Exact refine over KERNEL-CARRIED candidate rows (already dequantized
-    fp32): same arithmetic as ``_exact_refine``, minus the HBM gather.
-    Returns (vals, pos) — pos indexes the carried candidate axis."""
-    d2 = jnp.sum((queries[:, None, :] - scan_rows) ** 2, axis=-1)
-    return jax.lax.top_k(-d2, k)
 
 
 def _pallas_candidates(index: FlatIndex, queries: Array, kk: int,
@@ -241,35 +232,6 @@ def search(index: FlatIndex, queries: Array, k: int, block_rows: int = 0,
     return _exact_refine(index.vectors, queries, cand, k_out,
                          scales=index.scales)
 
-
-@partial(jax.jit, static_argnames=("k", "use_pallas"))
-def search_rows(index: FlatIndex, queries: Array, k: int, payload_v: Array,
-                payload_f: Array, *, use_pallas: bool = False):
-    """Gather-free top-k: returns the winners' PAYLOAD ROWS with the ids.
-
-    payload_v (n, dv) / payload_f (n, m) are row-aligned with the corpus
-    (for serving: the normalized originals used by combined-score re-rank).
-    Returns (scores (q,k), ids (q,k), rows_v (q,k,dv), rows_f (q,k,m)) with
-    (scores, ids) bit-identical to ``search``. On the Pallas path the rows
-    ride out of the scoring kernel's VMEM (no HBM gather); the jnp reference
-    path gathers by id, which is the semantic definition of the output.
-    """
-    n = index.size
-    k_out = min(k, n)
-    kk = min(n, k_out + REFINE_PAD)
-
-    if use_pallas:
-        _, cand, scan_rows, rows_v, rows_f = ops.score_topk_rows_padded(
-            index.vectors, index.sq_norms, payload_v, payload_f, queries, kk,
-            scales=index.scales)
-        vals, pos = _refine_carried(scan_rows, queries, k_out)
-        ids = jnp.take_along_axis(cand, pos, axis=-1)
-        rows_v = jnp.take_along_axis(rows_v, pos[..., None], axis=1)
-        rows_f = jnp.take_along_axis(rows_f, pos[..., None], axis=1)
-        return vals, ids, rows_v, rows_f
-
-    vals, ids = search(index, queries, k, use_pallas=False)
-    return vals, ids, payload_v[ids], payload_f[ids]
 
 
 @partial(jax.jit, static_argnames=("k", "use_pallas"))

@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.core.clustering import kmeans, assign
 from repro.index import quant
+from repro.index.flat import row_sq_norms
 from repro.index.slab import build_grouped
 from repro.kernels import ops
 
@@ -71,14 +72,6 @@ class IVFIndex:
                **opts):
         """SearchBackend protocol entry point."""
         return search(self, queries, k, use_pallas=use_pallas, **opts)
-
-    def search_rows(self, queries: Array, k: int, payload_v: Array,
-                    payload_f: Array, *, grouped_pv=None, grouped_pf=None,
-                    use_pallas: bool = False, **opts):
-        """Gather-free SearchBackend entry point (rows, not just ids)."""
-        return search_rows(self, queries, k, payload_v, payload_f,
-                           grouped_pv, grouped_pf, use_pallas=use_pallas,
-                           **opts)
 
     def slab(self):
         """The serving-layout view of this index (see ``repro.index.slab``):
@@ -127,7 +120,7 @@ def build(vectors: Array, nlist: int, rng: Array | None = None,
     else:
         if storage_dtype is not None:
             vectors = vectors.astype(storage_dtype)
-        sq_norms = jnp.sum(vectors.astype(jnp.float32) ** 2, axis=-1)
+        sq_norms = row_sq_norms(vectors)
     grouped, grouped_sq, valid = _grouped_slabs(vectors, sq_norms, lists)
     if scales is not None:
         grouped_scales = _group_scales(scales, lists)
@@ -205,42 +198,6 @@ def search(index: IVFIndex, queries: Array, k: int, nprobe: int = 8,
     return jax.vmap(one_query)(queries, q2[:, 0], probe)
 
 
-@partial(jax.jit, static_argnames=("k", "nprobe", "use_pallas"))
-def search_rows(index: IVFIndex, queries: Array, k: int, payload_v: Array,
-                payload_f: Array, grouped_pv=None, grouped_pf=None,
-                nprobe: int = 8, *, use_pallas: bool = False):
-    """Gather-free probed search: returns the winners' PAYLOAD ROWS too.
-
-    payload_v (n, dv) / payload_f (n, m) are corpus-row-aligned (the re-rank
-    originals); grouped_pv (nlist, max_list, dv) / grouped_pf are the same
-    payloads in the grouped serving layout (built once by the engine via
-    ``build_grouped_payload``), which the rows-returning dedup kernel streams
-    through VMEM. Returns (scores (q,k), ids (q,k), rows_v (q,k,dv), rows_f
-    (q,k,m)) with (scores, ids) identical to ``search``; unfilled (-inf)
-    slots carry id 0 and corpus row 0's payload, matching the id-gather
-    convention exactly (the phantom candidate competes in the final top-k).
-    """
-    nprobe = min(nprobe, index.nlist)
-    if use_pallas:
-        q2 = jnp.sum(queries * queries, axis=-1, keepdims=True)
-        c2 = jnp.sum(index.centroids * index.centroids, axis=-1)
-        _, probe = ops.score_topk_padded(index.centroids, c2, queries, nprobe)
-        uniq, member = ops.dedup_probes(probe.astype(jnp.int32), index.nlist)
-        vals, flat_ids, rows_v, rows_f = ops.ivf_score_topk_dedup_rows(
-            index.grouped, index.grouped_sq, index.valid, uniq, member,
-            queries, grouped_pv, grouped_pf, k,
-            scales=index.grouped_scales)
-        cand = index.lists.reshape(-1)[flat_ids]
-        vals = vals - q2
-        dead = jnp.isneginf(vals)
-        idx = jnp.where(dead, 0, jnp.maximum(cand, 0))
-        rows_v = jnp.where(dead[..., None], payload_v[0], rows_v)
-        rows_f = jnp.where(dead[..., None], payload_f[0], rows_f)
-        return vals, idx, rows_v, rows_f
-
-    vals, idx = search(index, queries, k, nprobe=nprobe, use_pallas=False)
-    return vals, idx, payload_v[idx], payload_f[idx]
-
 
 # ---------------------------------------------------------------------------
 # Filter-algebra candidate generation (mask / routed plans)
@@ -317,16 +274,6 @@ def eligible_lists(lists_np: np.ndarray, elig_np: np.ndarray):
     uniq = np.full((slots,), ids[0], np.int32)
     uniq[:n_live] = ids
     return uniq, n_live
-
-
-def build_grouped_payload(payload: Array, lists: Array) -> Array:
-    """Materialise a corpus-row-aligned payload (n, x) in the grouped
-    (nlist, max_list, x) serving layout (zeros on -1 padded slots), so the
-    rows-returning dedup kernel can stream payload slabs with the same
-    scalar-prefetch indirection as the corpus slabs."""
-    safe = jnp.maximum(lists, 0)
-    rows = payload[safe]                     # (nlist, max_list, x)
-    return jnp.where((lists >= 0)[..., None], rows, 0.0)
 
 
 def add(index: IVFIndex, new_vectors: Array) -> IVFIndex:

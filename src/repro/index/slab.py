@@ -96,6 +96,31 @@ def _put(mesh: Mesh, axes: Tuple[str, ...], x: Array) -> Array:
     return jax.device_put(x, NamedSharding(mesh, P(axes)))
 
 
+@jax.jit
+def take_rows(x: Array, idx: Array, fill: Array) -> Array:
+    """``x[idx]`` along dim 0 (``idx`` of any rank), ``fill`` where idx < 0."""
+    keep = (idx >= 0).reshape(idx.shape + (1,) * (x.ndim - 1))
+    return jnp.where(keep, x[jnp.maximum(idx, 0)], fill.astype(x.dtype))
+
+
+def put_gathered(mesh: Mesh, axes: Tuple[str, ...], x: Array, idx,
+                 fill) -> Array:
+    """``_put(mesh, axes, take_rows(x, idx, fill))``, built one device's
+    block at a time: ``x`` stays where it is and no device ever holds more
+    than its own block of the result. Placing a whole permuted slab on the
+    device that holds the unsharded index first would need a second copy
+    of the corpus there."""
+    idx = np.asarray(idx)
+    shape = idx.shape + x.shape[1:]
+    sharding = NamedSharding(mesh, P(axes))
+    fill = jnp.asarray(fill, x.dtype)
+    blocks = [jax.device_put(take_rows(x, jnp.asarray(idx[where[0]]), fill),
+                             dev)
+              for dev, where in
+              sharding.addressable_devices_indices_map(shape).items()]
+    return jax.make_array_from_single_device_arrays(shape, sharding, blocks)
+
+
 def pad_dim0(x: Array, to: int, value) -> Array:
     pad = to - x.shape[0]
     if pad <= 0:
@@ -204,22 +229,19 @@ class FlatSlab:
         else:
             raise ValueError(f"unknown placement {placement!r}")
         n_pad = -n % ns
-        vec = pad_dim0(self.vectors[row_ids], n + n_pad, 0)
-        sq = pad_dim0(self.sq_norms[row_ids], n + n_pad, jnp.inf)
-        ids = pad_dim0(row_ids, n + n_pad, -1)
+        ids = np.asarray(pad_dim0(row_ids, n + n_pad, -1))
         scales = None
         if self.scales is not None:
-            scales = _put(mesh, axes,
-                          pad_dim0(self.scales[row_ids], n + n_pad, 1.0))
+            scales = put_gathered(mesh, axes, self.scales, ids, 1.0)
         attrs_sh = None
         if attrs is not None:
-            a32 = jnp.asarray(attrs, jnp.float32)
-            attrs_sh = _put(mesh, axes,
-                            pad_dim0(a32[row_ids], n + n_pad, jnp.nan))
+            attrs_sh = put_gathered(mesh, axes,
+                                    jnp.asarray(attrs, jnp.float32), ids,
+                                    jnp.nan)
         return ShardedFlatSlab(
-            vectors=_put(mesh, axes, vec),
-            sq_norms=_put(mesh, axes, sq),
-            row_ids=_put(mesh, axes, ids),
+            vectors=put_gathered(mesh, axes, self.vectors, ids, 0),
+            sq_norms=put_gathered(mesh, axes, self.sq_norms, ids, jnp.inf),
+            row_ids=_put(mesh, axes, jnp.asarray(ids)),
             scales=scales,
             attrs=attrs_sh,
             mesh=mesh, axes=axes, n_real=n,
@@ -352,39 +374,30 @@ class IVFSlab:
             raise ValueError(f"unknown placement {placement!r}")
         slot_of_list = (shard_of * lpp + slot_in_shard).astype(np.int32)
 
-        d = self.grouped.shape[-1]
-        grouped = jnp.zeros((ns * lpp, max_list, d), self.grouped.dtype)
-        grouped_sq = jnp.full((ns * lpp, max_list), jnp.inf,
-                              self.grouped_sq.dtype)
-        valid = jnp.zeros((ns * lpp, max_list), self.valid.dtype)
-        lists = jnp.full((ns * lpp, max_list), -1, self.lists.dtype)
-        slots = jnp.asarray(slot_of_list)
-        grouped = grouped.at[slots].set(self.grouped)
-        grouped_sq = grouped_sq.at[slots].set(self.grouped_sq)
-        valid = valid.at[slots].set(self.valid)
-        lists = lists.at[slots].set(self.lists)
+        # source list of each storage slot; -1 = an empty or sentinel slot
+        src = np.full((ns * lpp,), -1, np.int32)
+        src[slot_of_list] = np.arange(nlist, dtype=np.int32)
+
+        def place(x, fill):
+            return put_gathered(mesh, axes, x, src, fill)
+
         grouped_scales = None
         if self.grouped_scales is not None:
-            gs = jnp.ones((ns * lpp, max_list), jnp.float32)
-            grouped_scales = _put(mesh, axes,
-                                  gs.at[slots].set(self.grouped_scales))
+            grouped_scales = place(self.grouped_scales, 1.0)
         attrs_sh = None
         if attrs is not None:
-            a32 = jnp.asarray(attrs, jnp.float32)
-            m = a32.shape[-1]
-            ga = jnp.where((self.lists >= 0)[..., None],
-                           a32[jnp.maximum(self.lists, 0)],
-                           jnp.nan)                    # (nlist, max_list, m)
-            full = jnp.full((ns * lpp, max_list, m), jnp.nan, jnp.float32)
-            attrs_sh = _put(mesh, axes, full.at[slots].set(ga))
+            # (nlist, max_list, m) raw attributes, NaN on pad slots
+            ga = take_rows(jnp.asarray(attrs, jnp.float32), self.lists,
+                           jnp.float32(jnp.nan))
+            attrs_sh = place(ga, jnp.nan)
         return ShardedIVFSlab(
             centroids=self.centroids,
             c_sq=jnp.sum(self.centroids.astype(jnp.float32) ** 2, axis=-1),
-            slot_of_list=slots,
-            grouped=_put(mesh, axes, grouped),
-            grouped_sq=_put(mesh, axes, grouped_sq),
-            valid=_put(mesh, axes, valid),
-            lists=_put(mesh, axes, lists),
+            slot_of_list=jnp.asarray(slot_of_list),
+            grouped=place(self.grouped, 0),
+            grouped_sq=place(self.grouped_sq, jnp.inf),
+            valid=place(self.valid, 0),
+            lists=place(self.lists, -1),
             mesh=mesh, axes=axes, nlist=nlist, max_list=max_list,
             lists_per_shard=lp, placement=placement,
             grouped_scales=grouped_scales,

@@ -32,7 +32,7 @@ def _kernel(v_ref, f_ref, proj_ref, alpha_ref, mv_ref, sv_ref, mf_ref, sf_ref,
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def fused_transform(v, f, proj, alpha, mean_v, std_v, mean_f, std_f,
-                    *, block_rows: int = DEF_BLOCK_ROWS, interpret: bool = True):
+                    *, block_rows: int = DEF_BLOCK_ROWS, interpret: bool):
     """v: (n, d); f: (n, m); proj: (m, d). Returns transformed (n, d)."""
     n, d = v.shape
     m = f.shape[-1]

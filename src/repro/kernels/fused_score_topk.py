@@ -7,8 +7,16 @@ the sequential corpus-block grid dimension. The corpus is therefore streamed
 through VMEM exactly once, and no (q x n) score matrix ever exists in HBM —
 the k-selection is fused into the scan.
 
-Top-k selection uses an unrolled k-step max/mask sweep (max + iota-argmin)
-instead of lax.top_k so every op lowers to plain TPU vector reductions.
+Top-k selection is a k-step max/mask sweep (max + iota-argmin) in a
+``fori_loop`` instead of lax.top_k, so every op lowers to plain TPU vector
+reductions and the kernel's code size does not grow with k. The running
+top-k and the block's scores stay two separate operands of the sweep (no
+lane concatenation at an unaligned offset).
+
+TPU layout: per-row operands (squared norms, int8 scales, the eligibility
+mask) are carried lane-major as (1, n) rows and the per-query squared norms
+as a (q, 1) column, so every block's last two dimensions tile as (8, 128)
+(a 1-D block would not match XLA's HBM tiling of the operand).
 """
 from __future__ import annotations
 
@@ -17,67 +25,94 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEF_BLOCK_ROWS = 128
 DEF_BLOCK_Q = 64
 NEG_INF = float("-inf")
 
-
-def _select_topk_pos(vals, ids, k: int):
-    """Unrolled first-occurrence top-k over the last axis, ALSO returning the
-    winners' positions along that axis. vals: (q, c) -> ((q, k),)*3.
-
-    The positions are what the rows-returning kernels key their VMEM row
-    copy-through on (position < k = keep the running row, >= k = take row
-    ``pos - k`` of the streamed block)."""
-    c = vals.shape[-1]
-    iota = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
-    out_v, out_i, out_p = [], [], []
-    cur = vals
-    for _ in range(k):
-        m = jnp.max(cur, axis=-1, keepdims=True)
-        pos = jnp.min(jnp.where(cur == m, iota, c), axis=-1, keepdims=True)
-        sel = iota == pos
-        out_v.append(m[:, 0])
-        out_i.append(jnp.sum(jnp.where(sel, ids, 0), axis=-1))
-        out_p.append(pos[:, 0])
-        cur = jnp.where(sel, NEG_INF, cur)
-    return (jnp.stack(out_v, axis=-1), jnp.stack(out_i, axis=-1),
-            jnp.stack(out_p, axis=-1))
+# scoped-VMEM ceiling a kernel may ask for: v5e has 128 MiB of VMEM per core,
+# of which Mosaic grants 16 MiB unless told otherwise
+VMEM_CAP = 100 * 2**20
 
 
-def _select_topk(vals, ids, k: int):
-    """Unrolled first-occurrence top-k over the last axis. vals: (q, c)."""
-    out_v, out_i, _ = _select_topk_pos(vals, ids, k)
-    return out_v, out_i
+def compiler_params(vmem_bytes: int, semantics):
+    """Mosaic parameters for a kernel whose blocks and temporaries need about
+    ``vmem_bytes`` of VMEM (raised above Mosaic's 16 MiB default when the
+    estimate asks for it, capped at ``VMEM_CAP``)."""
+    limit = min(max(32 * 2**20, 2 * vmem_bytes), VMEM_CAP)
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=int(limit))
 
 
-def pick_rows(pos, run_rows, block_rows, k: int):
-    """Copy winner rows through VMEM by top-k position (no HBM gather).
+def merge_topk(run_v, run_i, blk_v, blk_i, k: int):
+    """First-occurrence top-k of the concatenation [running | block] along
+    the last axis, without materialising the concatenation.
 
-    pos: (q, k) positions into the concatenated [running-k | block] axis;
-    run_rows: (q, k, d) rows carried so far; block_rows: (bn, d) this grid
-    step's payload block. The selection is two one-hot matmuls (exact: each
-    output row sums one ``1.0 * x`` with zeros), so it lowers to MXU dots
-    instead of a gather.
+    run_v/run_i: (q, kr); blk_v/blk_i: (q, c). Returns (vals, ids), each
+    (q, k), descending by value. Ties go to the lowest position, exactly
+    like ``lax.top_k`` over the concatenation.
     """
-    q, kk = pos.shape
-    bn = block_rows.shape[0]
-    iota_k = jax.lax.broadcasted_iota(jnp.int32, (q, kk, k), 2)
-    sel_run = (iota_k == pos[:, :, None]).astype(jnp.float32)
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (q, kk, bn), 2)
-    sel_blk = (iota_b == (pos[:, :, None] - k)).astype(jnp.float32)
-    kept = jax.lax.dot_general(
-        sel_run, run_rows, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    taken = jax.lax.dot_general(
-        sel_blk, block_rows.astype(jnp.float32), (((2,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return kept + taken
+    q, kr = run_v.shape
+    c = blk_v.shape[-1]
+    big = kr + c
+    iota_r = jax.lax.broadcasted_iota(jnp.int32, (q, kr), 1)
+    iota_b = jax.lax.broadcasted_iota(jnp.int32, (q, c), 1) + kr
+    slot = jax.lax.broadcasted_iota(jnp.int32, (q, k), 1)
+
+    def body(t, carry):
+        cur_r, cur_b, out_v, out_i = carry
+        m = jnp.maximum(jnp.max(cur_r, axis=-1, keepdims=True),
+                        jnp.max(cur_b, axis=-1, keepdims=True))
+        pos = jnp.minimum(
+            jnp.min(jnp.where(cur_r == m, iota_r, big), axis=-1,
+                    keepdims=True),
+            jnp.min(jnp.where(cur_b == m, iota_b, big), axis=-1,
+                    keepdims=True))
+        sel_r = iota_r == pos
+        sel_b = iota_b == pos
+        ident = (jnp.sum(jnp.where(sel_r, run_i, 0), axis=-1, keepdims=True)
+                 + jnp.sum(jnp.where(sel_b, blk_i, 0), axis=-1,
+                           keepdims=True))
+        here = slot == t
+        return (jnp.where(sel_r, NEG_INF, cur_r),
+                jnp.where(sel_b, NEG_INF, cur_b),
+                jnp.where(here, m, out_v),
+                jnp.where(here, ident, out_i))
+
+    init = (run_v, blk_v, jnp.full((q, k), NEG_INF, jnp.float32),
+            jnp.zeros((q, k), jnp.int32))
+    _, _, vals, ids = jax.lax.fori_loop(0, k, body, init)
+    return vals, ids
 
 
-def _kernel(x_ref, xsq_ref, q_ref, qsq_ref, vals_ref, idx_ref, *, k: int,
-            block_rows: int):
+def _block_scores(j, x_ref, xsq_ref, scale_ref, mask_ref, q_ref, qsq_ref,
+                  block_rows: int):
+    """(scores (bq, bn), global row ids (bq, bn)) of one grid cell. ``scale_ref``/``mask_ref`` are None for the plain variants.
+    The int8 scale multiplies the matmul OUTPUT column (fp32 accumulation)."""
+    x = x_ref[...].astype(jnp.float32)                 # (bn, d)
+    q = q_ref[...]                                      # (bq, d)
+    scores = 2.0 * jnp.dot(q, x.T, preferred_element_type=jnp.float32)
+    if scale_ref is not None:
+        scores = scores * scale_ref[...]                # (1, bn)
+    scores = scores - xsq_ref[...] - qsq_ref[...]       # (1, bn), (bq, 1)
+    if mask_ref is not None:
+        scores = jnp.where(mask_ref[...] > 0.5, scores, NEG_INF)
+    gids = j * block_rows + jax.lax.broadcasted_iota(jnp.int32, scores.shape,
+                                                     1)
+    return scores, gids
+
+
+def _scan_kernel(*refs, k: int, block_rows: int, has_scale: bool,
+                 has_mask: bool):
+    """Plain / int8-scaled / masked / masked+scaled scan: ineligible rows
+    (mask 0) score -inf inside the scan — the filter algebra's in-kernel
+    mask plan."""
+    refs = list(refs)
+    x_ref, xsq_ref = refs.pop(0), refs.pop(0)
+    scale_ref = refs.pop(0) if has_scale else None
+    mask_ref = refs.pop(0) if has_mask else None
+    q_ref, qsq_ref, vals_ref, idx_ref = refs
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -85,93 +120,10 @@ def _kernel(x_ref, xsq_ref, q_ref, qsq_ref, vals_ref, idx_ref, *, k: int,
         vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
         idx_ref[...] = jnp.zeros_like(idx_ref)
 
-    x = x_ref[...]                     # (bn, d)
-    q = q_ref[...]                     # (bq, d)
-    scores = 2.0 * jnp.dot(q, x.T, preferred_element_type=jnp.float32)
-    scores = scores - xsq_ref[...][None, :] - qsq_ref[...][:, None]
-    gids = j * block_rows + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-
-    run_v = vals_ref[...]
-    run_i = idx_ref[...]
-    cat_v = jnp.concatenate([run_v, scores], axis=-1)
-    cat_i = jnp.concatenate([run_i, gids], axis=-1)
-    new_v, new_i = _select_topk(cat_v, cat_i, k)
-    vals_ref[...] = new_v.astype(vals_ref.dtype)
-    idx_ref[...] = new_i
-
-
-def _scaled_kernel(x_ref, xsq_ref, scale_ref, q_ref, qsq_ref, vals_ref,
-                   idx_ref, *, k: int, block_rows: int):
-    """Int8 variant: rows stream as int8 codes, the per-row scale multiplies
-    the matmul OUTPUT column — fp32 accumulation, one extra VPU multiply."""
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
-        idx_ref[...] = jnp.zeros_like(idx_ref)
-
-    x = x_ref[...].astype(jnp.float32)  # (bn, d)
-    q = q_ref[...]                      # (bq, d)
-    scores = 2.0 * jnp.dot(q, x.T, preferred_element_type=jnp.float32)
-    scores = scores * scale_ref[...][None, :]
-    scores = scores - xsq_ref[...][None, :] - qsq_ref[...][:, None]
-    gids = j * block_rows + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-
-    cat_v = jnp.concatenate([vals_ref[...], scores], axis=-1)
-    cat_i = jnp.concatenate([idx_ref[...], gids], axis=-1)
-    new_v, new_i = _select_topk(cat_v, cat_i, k)
-    vals_ref[...] = new_v.astype(vals_ref.dtype)
-    idx_ref[...] = new_i
-
-
-def _masked_kernel(x_ref, xsq_ref, mask_ref, q_ref, qsq_ref, vals_ref,
-                   idx_ref, *, k: int, block_rows: int):
-    """Filtered variant: a per-row 0/1 candidate mask streams alongside the
-    corpus block and ineligible rows score -inf INSIDE the scan — the filter
-    algebra's in-kernel mask plan. One extra (bn,) VPU select per block."""
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
-        idx_ref[...] = jnp.zeros_like(idx_ref)
-
-    x = x_ref[...]                     # (bn, d)
-    q = q_ref[...]                     # (bq, d)
-    scores = 2.0 * jnp.dot(q, x.T, preferred_element_type=jnp.float32)
-    scores = scores - xsq_ref[...][None, :] - qsq_ref[...][:, None]
-    scores = jnp.where(mask_ref[...][None, :] > 0.5, scores, NEG_INF)
-    gids = j * block_rows + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-
-    cat_v = jnp.concatenate([vals_ref[...], scores], axis=-1)
-    cat_i = jnp.concatenate([idx_ref[...], gids], axis=-1)
-    new_v, new_i = _select_topk(cat_v, cat_i, k)
-    vals_ref[...] = new_v.astype(vals_ref.dtype)
-    idx_ref[...] = new_i
-
-
-def _masked_scaled_kernel(x_ref, xsq_ref, scale_ref, mask_ref, q_ref, qsq_ref,
-                          vals_ref, idx_ref, *, k: int, block_rows: int):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
-        idx_ref[...] = jnp.zeros_like(idx_ref)
-
-    x = x_ref[...].astype(jnp.float32)  # (bn, d) int8 codes
-    q = q_ref[...]                      # (bq, d)
-    scores = 2.0 * jnp.dot(q, x.T, preferred_element_type=jnp.float32)
-    scores = scores * scale_ref[...][None, :]
-    scores = scores - xsq_ref[...][None, :] - qsq_ref[...][:, None]
-    scores = jnp.where(mask_ref[...][None, :] > 0.5, scores, NEG_INF)
-    gids = j * block_rows + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-
-    cat_v = jnp.concatenate([vals_ref[...], scores], axis=-1)
-    cat_i = jnp.concatenate([idx_ref[...], gids], axis=-1)
-    new_v, new_i = _select_topk(cat_v, cat_i, k)
-    vals_ref[...] = new_v.astype(vals_ref.dtype)
+    scores, gids = _block_scores(j, x_ref, xsq_ref, scale_ref, mask_ref,
+                                 q_ref, qsq_ref, block_rows)
+    new_v, new_i = merge_topk(vals_ref[...], idx_ref[...], scores, gids, k)
+    vals_ref[...] = new_v
     idx_ref[...] = new_i
 
 
@@ -186,11 +138,16 @@ def _check_tiling(n, nq, k, block_rows, block_q):
     return block_rows, block_q
 
 
+def _row(x):
+    """(n,) per-row operand -> (1, n) lane-major row."""
+    return x.astype(jnp.float32).reshape(1, -1)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("k", "block_rows", "block_q", "interpret"))
 def score_topk(corpus, sq_norms, queries, k: int, *, scales=None, mask=None,
                block_rows: int = DEF_BLOCK_ROWS, block_q: int = DEF_BLOCK_Q,
-               interpret: bool = True):
+               interpret: bool):
     """corpus: (n, d); sq_norms: (n,); queries: (q, d).
 
     Returns (scores (q, k), ids (q, k)) — negative squared L2, descending.
@@ -203,133 +160,31 @@ def score_topk(corpus, sq_norms, queries, k: int, *, scales=None, mask=None,
     nq = queries.shape[0]
     block_rows, block_q = _check_tiling(n, nq, k, block_rows, block_q)
     grid = (nq // block_q, n // block_rows)
-    qsq = jnp.sum(queries.astype(jnp.float32) ** 2, axis=-1)
+    qsq = jnp.sum(queries.astype(jnp.float32) ** 2, axis=-1, keepdims=True)
 
     row_spec = pl.BlockSpec((block_rows, d), lambda i, j: (j, 0))
-    rsq_spec = pl.BlockSpec((block_rows,), lambda i, j: (j,))
-    q_spec = pl.BlockSpec((block_q, d), lambda i, j: (i, 0))
-    qsq_spec = pl.BlockSpec((block_q,), lambda i, j: (i,))
-    out_specs = (
-        pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
-        pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
-    )
-    out_shape = (
-        jax.ShapeDtypeStruct((nq, k), jnp.float32),
-        jax.ShapeDtypeStruct((nq, k), jnp.int32),
-    )
-    if scales is None and mask is None:
-        kernel = functools.partial(_kernel, k=k, block_rows=block_rows)
-        in_specs = [row_spec, rsq_spec, q_spec, qsq_spec]
-        args = (corpus, sq_norms, queries, qsq)
-    elif scales is None:
-        kernel = functools.partial(_masked_kernel, k=k, block_rows=block_rows)
-        in_specs = [row_spec, rsq_spec, rsq_spec, q_spec, qsq_spec]
-        args = (corpus, sq_norms, mask, queries, qsq)
-    elif mask is None:
-        kernel = functools.partial(_scaled_kernel, k=k, block_rows=block_rows)
-        in_specs = [row_spec, rsq_spec, rsq_spec, q_spec, qsq_spec]
-        args = (corpus, sq_norms, scales, queries, qsq)
-    else:
-        kernel = functools.partial(_masked_scaled_kernel, k=k,
-                                   block_rows=block_rows)
-        in_specs = [row_spec, rsq_spec, rsq_spec, rsq_spec, q_spec, qsq_spec]
-        args = (corpus, sq_norms, scales, mask, queries, qsq)
-    vals, idx = pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs, out_shape=out_shape, interpret=interpret,
-    )(*args)
-    return vals, idx
-
-
-def _rows_kernel(x_ref, xsq_ref, scale_ref, pv_ref, pf_ref, q_ref, qsq_ref,
-                 vals_ref, idx_ref, sr_ref, rv_ref, rf_ref, *, k: int,
-                 block_rows: int):
-    """Rows-returning variant: alongside (vals, ids) the kernel carries the
-    winners' DEQUANTIZED scan rows plus their payload rows (re-rank vectors
-    and filter values) in the output refs, so the caller never gathers from
-    HBM. The per-row scale operand is all-ones for fp32/bf16 storage
-    (multiplying by 1.0 is exact, so (vals, ids) match the plain kernel
-    bitwise)."""
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
-        idx_ref[...] = jnp.zeros_like(idx_ref)
-        sr_ref[...] = jnp.zeros_like(sr_ref)
-        rv_ref[...] = jnp.zeros_like(rv_ref)
-        rf_ref[...] = jnp.zeros_like(rf_ref)
-
-    x = x_ref[...].astype(jnp.float32)  # (bn, d)
-    scale = scale_ref[...]              # (bn,)
-    q = q_ref[...]                      # (bq, d)
-    scores = 2.0 * jnp.dot(q, x.T, preferred_element_type=jnp.float32)
-    scores = scores * scale[None, :]
-    scores = scores - xsq_ref[...][None, :] - qsq_ref[...][:, None]
-    gids = j * block_rows + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-
-    run_sr = sr_ref[...]
-    run_rv = rv_ref[...]
-    run_rf = rf_ref[...]
-    cat_v = jnp.concatenate([vals_ref[...], scores], axis=-1)
-    cat_i = jnp.concatenate([idx_ref[...], gids], axis=-1)
-    new_v, new_i, pos = _select_topk_pos(cat_v, cat_i, k)
-    vals_ref[...] = new_v.astype(vals_ref.dtype)
-    idx_ref[...] = new_i
-    sr_ref[...] = pick_rows(pos, run_sr, x * scale[:, None], k)
-    rv_ref[...] = pick_rows(pos, run_rv, pv_ref[...], k)
-    rf_ref[...] = pick_rows(pos, run_rf, pf_ref[...], k)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("k", "block_rows", "block_q", "interpret"))
-def score_topk_rows(corpus, sq_norms, payload_v, payload_f, queries, k: int,
-                    *, scales=None, block_rows: int = DEF_BLOCK_ROWS,
-                    block_q: int = DEF_BLOCK_Q, interpret: bool = True):
-    """Gather-free flat scan: corpus (n, d); payload_v (n, dv); payload_f
-    (n, m); queries (q, d).
-
-    Returns (scores (q, k), ids (q, k), scan_rows (q, k, d) fp32 dequantized
-    stored rows for the exact-refine pass, rows_v (q, k, dv), rows_f
-    (q, k, m)) — (scores, ids) bit-identical to ``score_topk``.
-    """
-    n, d = corpus.shape
-    nq = queries.shape[0]
-    dv = payload_v.shape[-1]
-    m = payload_f.shape[-1]
-    block_rows, block_q = _check_tiling(n, nq, k, block_rows, block_q)
-    grid = (nq // block_q, n // block_rows)
-    qsq = jnp.sum(queries.astype(jnp.float32) ** 2, axis=-1)
-    if scales is None:
-        scales = jnp.ones((n,), jnp.float32)
-
-    kernel = functools.partial(_rows_kernel, k=k, block_rows=block_rows)
+    per_row = pl.BlockSpec((1, block_rows), lambda i, j: (0, j))
+    in_specs = [row_spec, per_row]
+    args = [corpus, _row(sq_norms)]
+    for extra in (scales, mask):
+        if extra is not None:
+            in_specs.append(per_row)
+            args.append(_row(extra))
+    in_specs += [pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
+                 pl.BlockSpec((block_q, 1), lambda i, j: (i, 0))]
+    args += [queries, qsq]
+    out_spec = pl.BlockSpec((block_q, k), lambda i, j: (i, 0))
+    kernel = functools.partial(_scan_kernel, k=k, block_rows=block_rows,
+                               has_scale=scales is not None,
+                               has_mask=mask is not None)
+    tile = block_q * (k + block_rows) * 4
+    vmem = (2 * block_rows * d * corpus.dtype.itemsize
+            + 2 * block_q * d * 4 + 12 * tile)
     return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_rows,), lambda i, j: (j,)),
-            pl.BlockSpec((block_rows,), lambda i, j: (j,)),
-            pl.BlockSpec((block_rows, dv), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_rows, m), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_q,), lambda i, j: (i,)),
-        ],
-        out_specs=(
-            pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_q, k, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((block_q, k, dv), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((block_q, k, m), lambda i, j: (i, 0, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((nq, k), jnp.float32),
-            jax.ShapeDtypeStruct((nq, k), jnp.int32),
-            jax.ShapeDtypeStruct((nq, k, d), jnp.float32),
-            jax.ShapeDtypeStruct((nq, k, dv), jnp.float32),
-            jax.ShapeDtypeStruct((nq, k, m), jnp.float32),
-        ),
+        kernel, grid=grid, in_specs=in_specs,
+        out_specs=(out_spec, out_spec),
+        out_shape=(jax.ShapeDtypeStruct((nq, k), jnp.float32),
+                   jax.ShapeDtypeStruct((nq, k), jnp.int32)),
+        compiler_params=compiler_params(vmem, ("parallel", "arbitrary")),
         interpret=interpret,
-    )(corpus, sq_norms, scales, payload_v, payload_f, queries, qsq)
+    )(*args)

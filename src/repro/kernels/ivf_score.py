@@ -19,6 +19,13 @@ list shared by many queries is DMA'd from HBM exactly once per batch instead
 of once per (query, probe) pair — with batch 64 x nprobe 8 over nlist 64 the
 slab traffic drops up to 8x, which is the win that matters on the
 bandwidth-bound serving path.
+
+TPU layout: the per-slot operands (squared norms, validity, int8 scales)
+enter as (nlist, 1, max_list) rows and the membership matrix as (s, 1, b),
+so every block's last two dimensions are whole or (8, 128)-aligned. A list
+longer than ``PAGE_BUDGET`` bytes of slab is scanned in pages of
+``page_rows`` rows (an extra sequential grid axis), which bounds the VMEM a
+grid step needs whatever the list-size skew.
 """
 from __future__ import annotations
 
@@ -29,12 +36,46 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.fused_score_topk import (_select_topk, _select_topk_pos,
-                                            pick_rows, NEG_INF)
+from repro.kernels.fused_score_topk import (NEG_INF, compiler_params,
+                                            merge_topk)
+
+# slab bytes one grid step may DMA before a list is paged
+PAGE_BUDGET = 2 * 2**20
 
 
-def _batch_kernel(probes_ref, slab_ref, sq_ref, valid_ref, q_ref, vals_ref,
-                  idx_ref, *, k: int, max_list: int):
+def page_rows(max_list: int, row_bytes: int) -> int:
+    """Rows per page: all of ``max_list`` when a whole list fits
+    ``PAGE_BUDGET``, else the largest multiple of 128 that divides it and
+    fits (lists padded to an odd length are scanned whole)."""
+    if max_list * row_bytes <= PAGE_BUDGET:
+        return max_list
+    best = max_list
+    for p in range(128, max_list, 128):
+        if max_list % p == 0 and p * row_bytes <= PAGE_BUDGET:
+            best = p
+    return best
+
+
+def _rows3(x):
+    """(a, c) per-slot operand -> (a, 1, c) f32 rows (one (1, c) tile each)."""
+    return x.astype(jnp.float32).reshape(x.shape[0], 1, x.shape[1])
+
+
+def _row_to_col(row):
+    """(1, c) -> (c, 1), exactly: each output sums one value with zeros (a
+    lane reduction, where a transpose of a one-row tile may not lower)."""
+    c = row.shape[-1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (c, c), 1))
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=-1, keepdims=True)
+
+
+def _batch_kernel(*refs, k: int, max_list: int, has_scale: bool):
+    refs = list(refs)
+    probes_ref, slab_ref, sq_ref = refs[:3]
+    refs = refs[3:]
+    sc_ref = refs.pop(0) if has_scale else None
+    valid_ref, q_ref, vals_ref, idx_ref = refs
     i = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -43,56 +84,24 @@ def _batch_kernel(probes_ref, slab_ref, sq_ref, valid_ref, q_ref, vals_ref,
         vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
         idx_ref[...] = jnp.zeros_like(idx_ref)
 
-    slab = slab_ref[...][0]            # (max_list, d)
-    sq = sq_ref[...][0]                # (max_list,)
-    ok = valid_ref[...][0]             # (max_list,) float 0/1
-    q = q_ref[...][0]                  # (d,)
-
-    s = 2.0 * jnp.dot(slab, q, preferred_element_type=jnp.float32) - sq
-    s = jnp.where(ok > 0.5, s, NEG_INF)[None, :]        # (1, max_list)
+    slab = slab_ref[0].astype(jnp.float32)               # (max_list, d)
+    q = q_ref[0]                                         # (1, d)
+    s = 2.0 * jnp.dot(q, slab.T, preferred_element_type=jnp.float32)
+    if sc_ref is not None:
+        s = s * sc_ref[0]
+    s = s - sq_ref[0]                                    # (1, max_list)
+    s = jnp.where(valid_ref[0] > 0.5, s, NEG_INF)
     list_id = probes_ref[i, j]
     gids = (list_id * max_list
             + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-
-    cat_v = jnp.concatenate([vals_ref[...], s], axis=-1)
-    cat_i = jnp.concatenate([idx_ref[...], gids], axis=-1)
-    new_v, new_i = _select_topk(cat_v, cat_i, k)
-    vals_ref[...] = new_v.astype(vals_ref.dtype)
-    idx_ref[...] = new_i
-
-
-def _batch_scaled_kernel(probes_ref, slab_ref, sq_ref, sc_ref, valid_ref,
-                         q_ref, vals_ref, idx_ref, *, k: int, max_list: int):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
-        idx_ref[...] = jnp.zeros_like(idx_ref)
-
-    slab = slab_ref[...][0].astype(jnp.float32)   # (max_list, d) int8 codes
-    sq = sq_ref[...][0]                # (max_list,)
-    sc = sc_ref[...][0]                # (max_list,) per-row dequant scales
-    ok = valid_ref[...][0]             # (max_list,) float 0/1
-    q = q_ref[...][0]                  # (d,)
-
-    s = 2.0 * jnp.dot(slab, q, preferred_element_type=jnp.float32) * sc - sq
-    s = jnp.where(ok > 0.5, s, NEG_INF)[None, :]        # (1, max_list)
-    list_id = probes_ref[i, j]
-    gids = (list_id * max_list
-            + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-
-    cat_v = jnp.concatenate([vals_ref[...], s], axis=-1)
-    cat_i = jnp.concatenate([idx_ref[...], gids], axis=-1)
-    new_v, new_i = _select_topk(cat_v, cat_i, k)
-    vals_ref[...] = new_v.astype(vals_ref.dtype)
-    idx_ref[...] = new_i
+    new_v, new_i = merge_topk(vals_ref[0], idx_ref[0], s, gids, k)
+    vals_ref[0] = new_v
+    idx_ref[0] = new_i
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def ivf_score_topk_batch(grouped, grouped_sq, valid, probes, queries, k: int,
-                         *, scales=None, interpret: bool = True):
+                         *, scales=None, interpret: bool):
     """Multi-query probed search over the grouped slab layout.
 
     grouped: (nlist, max_list, d); grouped_sq: (nlist, max_list);
@@ -108,107 +117,69 @@ def ivf_score_topk_batch(grouped, grouped_sq, valid, probes, queries, k: int,
 
     probe_slab = pl.BlockSpec((1, max_list, d),
                               lambda i, j, probes: (probes[i, j], 0, 0))
-    probe_row = pl.BlockSpec((1, max_list),
-                             lambda i, j, probes: (probes[i, j], 0))
-    q_spec = pl.BlockSpec((1, d), lambda i, j, probes: (i, 0))
-    out_specs = (
-        pl.BlockSpec((1, k), lambda i, j, probes: (i, 0)),
-        pl.BlockSpec((1, k), lambda i, j, probes: (i, 0)),
-    )
-    out_shape = (
-        jax.ShapeDtypeStruct((b, k), jnp.float32),
-        jax.ShapeDtypeStruct((b, k), jnp.int32),
-    )
-    if scales is None:
-        kernel = functools.partial(_batch_kernel, k=k, max_list=max_list)
-        in_specs = [probe_slab, probe_row, probe_row, q_spec]
-        args = (probes, grouped, grouped_sq, valid, queries)
-    else:
-        kernel = functools.partial(_batch_scaled_kernel, k=k,
-                                   max_list=max_list)
-        in_specs = [probe_slab, probe_row, probe_row, probe_row, q_spec]
-        args = (probes, grouped, grouped_sq, scales, valid, queries)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, nprobe),
-        in_specs=in_specs,
-        out_specs=out_specs,
-    )
+    probe_row = pl.BlockSpec((1, 1, max_list),
+                             lambda i, j, probes: (probes[i, j], 0, 0))
+    per_query = lambda w: pl.BlockSpec((1, 1, w),  # noqa: E731
+                                       lambda i, j, probes: (i, 0, 0))
+    in_specs = [probe_slab, probe_row]
+    args = [probes, grouped, _rows3(grouped_sq)]
+    if scales is not None:
+        in_specs.append(probe_row)
+        args.append(_rows3(scales))
+    in_specs += [probe_row, per_query(d)]
+    args += [_rows3(valid), queries.reshape(b, 1, d)]
+    kernel = functools.partial(_batch_kernel, k=k, max_list=max_list,
+                               has_scale=scales is not None)
+    vmem = 2 * max_list * d * grouped.dtype.itemsize + 16 * (k + max_list) * 4
     vals, idx = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, nprobe), in_specs=in_specs,
+            out_specs=(per_query(k), per_query(k))),
+        out_shape=(jax.ShapeDtypeStruct((b, 1, k), jnp.float32),
+                   jax.ShapeDtypeStruct((b, 1, k), jnp.int32)),
+        compiler_params=compiler_params(vmem, ("parallel", "arbitrary")),
         interpret=interpret,
     )(*args)
-    return vals, idx
+    return vals[:, 0], idx[:, 0]
 
 
-def _dedup_kernel(uniq_ref, slab_ref, sq_ref, valid_ref, member_ref, q_ref,
-                  vals_ref, idx_ref, *, k: int, max_list: int):
+def _dedup_kernel(*refs, k: int, max_list: int, page: int, has_scale: bool):
+    """Probe-major scan of one (unique list, page) grid cell for the whole
+    query batch; queries that did not probe the list score -inf."""
+    refs = list(refs)
+    uniq_ref, slab_ref, sq_ref = refs[:3]
+    refs = refs[3:]
+    sc_ref = refs.pop(0) if has_scale else None
+    valid_ref, member_ref, q_ref, vals_ref, idx_ref = refs
     s = pl.program_id(0)
+    p = pl.program_id(1)
 
-    @pl.when(s == 0)
+    @pl.when((s == 0) & (p == 0))
     def _init():
         vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
         idx_ref[...] = jnp.zeros_like(idx_ref)
 
-    slab = slab_ref[...][0]            # (max_list, d)
-    sq = sq_ref[...][0]                # (max_list,)
-    ok = valid_ref[...][0]             # (max_list,) float 0/1
-    mem = member_ref[...][0]           # (b,) float 0/1: query probed this list
-    q = q_ref[...]                     # (b, d)
-
+    slab = slab_ref[0].astype(jnp.float32)              # (page, d)
+    q = q_ref[...]                                      # (b, d)
     scores = 2.0 * jnp.dot(q, slab.T, preferred_element_type=jnp.float32)
-    scores = scores - sq[None, :]                       # (b, max_list)
-    keep = (ok > 0.5)[None, :] & (mem > 0.5)[:, None]
+    if sc_ref is not None:
+        scores = scores * sc_ref[0]                     # (1, page)
+    scores = scores - sq_ref[0]
+    mem = _row_to_col(member_ref[0])                     # (b, 1)
+    keep = (valid_ref[0] > 0.5) & (mem > 0.5)
     scores = jnp.where(keep, scores, NEG_INF)
-    list_id = uniq_ref[s]
-    gids = (list_id * max_list
+    gids = (uniq_ref[s] * max_list + p * page
             + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1))
-
-    cat_v = jnp.concatenate([vals_ref[...], scores], axis=-1)
-    cat_i = jnp.concatenate([idx_ref[...], gids], axis=-1)
-    new_v, new_i = _select_topk(cat_v, cat_i, k)
-    vals_ref[...] = new_v.astype(vals_ref.dtype)
-    idx_ref[...] = new_i
-
-
-def _dedup_scaled_kernel(uniq_ref, slab_ref, sq_ref, sc_ref, valid_ref,
-                         member_ref, q_ref, vals_ref, idx_ref, *, k: int,
-                         max_list: int):
-    s = pl.program_id(0)
-
-    @pl.when(s == 0)
-    def _init():
-        vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
-        idx_ref[...] = jnp.zeros_like(idx_ref)
-
-    slab = slab_ref[...][0].astype(jnp.float32)   # (max_list, d) int8 codes
-    sq = sq_ref[...][0]                # (max_list,)
-    sc = sc_ref[...][0]                # (max_list,) per-row dequant scales
-    ok = valid_ref[...][0]             # (max_list,) float 0/1
-    mem = member_ref[...][0]           # (b,) float 0/1
-    q = q_ref[...]                     # (b, d)
-
-    scores = 2.0 * jnp.dot(q, slab.T, preferred_element_type=jnp.float32)
-    scores = scores * sc[None, :] - sq[None, :]         # (b, max_list)
-    keep = (ok > 0.5)[None, :] & (mem > 0.5)[:, None]
-    scores = jnp.where(keep, scores, NEG_INF)
-    list_id = uniq_ref[s]
-    gids = (list_id * max_list
-            + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1))
-
-    cat_v = jnp.concatenate([vals_ref[...], scores], axis=-1)
-    cat_i = jnp.concatenate([idx_ref[...], gids], axis=-1)
-    new_v, new_i = _select_topk(cat_v, cat_i, k)
-    vals_ref[...] = new_v.astype(vals_ref.dtype)
+    new_v, new_i = merge_topk(vals_ref[...], idx_ref[...], scores, gids, k)
+    vals_ref[...] = new_v
     idx_ref[...] = new_i
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def ivf_score_topk_dedup(grouped, grouped_sq, valid, uniq, member, queries,
                          k: int, *, scales=None, mask=None,
-                         interpret: bool = True):
+                         interpret: bool):
     """Probe-major batched slab search over the deduplicated probed lists.
 
     grouped: (nlist, max_list, d); grouped_sq/valid: (nlist, max_list);
@@ -225,145 +196,39 @@ def ivf_score_topk_dedup(grouped, grouped_sq, valid, uniq, member, queries,
     multiplies into the validity operand the kernel streams, so ineligible
     rows score -inf inside the scan (exact — both operands are 0/1).
     """
-    nlist, max_list, d = grouped.shape
     if mask is not None:
         valid = valid * mask
-    b = queries.shape[0]
-    slots = uniq.shape[0]
-
-    slab_spec = pl.BlockSpec((1, max_list, d), lambda s, uniq: (uniq[s], 0, 0))
-    row_spec = pl.BlockSpec((1, max_list), lambda s, uniq: (uniq[s], 0))
-    mem_spec = pl.BlockSpec((1, b), lambda s, uniq: (s, 0))
-    q_spec = pl.BlockSpec((b, d), lambda s, uniq: (0, 0))
-    out_specs = (
-        pl.BlockSpec((b, k), lambda s, uniq: (0, 0)),
-        pl.BlockSpec((b, k), lambda s, uniq: (0, 0)),
-    )
-    out_shape = (
-        jax.ShapeDtypeStruct((b, k), jnp.float32),
-        jax.ShapeDtypeStruct((b, k), jnp.int32),
-    )
-    if scales is None:
-        kernel = functools.partial(_dedup_kernel, k=k, max_list=max_list)
-        in_specs = [slab_spec, row_spec, row_spec, mem_spec, q_spec]
-        args = (uniq, grouped, grouped_sq, valid, member, queries)
-    else:
-        kernel = functools.partial(_dedup_scaled_kernel, k=k,
-                                   max_list=max_list)
-        in_specs = [slab_spec, row_spec, row_spec, row_spec, mem_spec, q_spec]
-        args = (uniq, grouped, grouped_sq, scales, valid, member, queries)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(slots,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-    )
-    vals, idx = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*args)
-    return vals, idx
-
-
-def _dedup_rows_kernel(uniq_ref, slab_ref, sq_ref, sc_ref, valid_ref,
-                       member_ref, pv_ref, pf_ref, q_ref, vals_ref, idx_ref,
-                       rv_ref, rf_ref, *, k: int, max_list: int):
-    """Rows-returning dedup variant: payload slabs (re-rank vectors and
-    filter values, grouped by list like the corpus slab) ride the same
-    scalar-prefetch indirection, and the winners' payload rows are carried
-    in the output refs via the one-hot copy-through — no HBM gather after
-    the kernel. The scale operand is all-ones for fp32/bf16 storage, so
-    (vals, ids) stay bit-identical to ``ivf_score_topk_dedup``."""
-    s = pl.program_id(0)
-
-    @pl.when(s == 0)
-    def _init():
-        vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
-        idx_ref[...] = jnp.zeros_like(idx_ref)
-        rv_ref[...] = jnp.zeros_like(rv_ref)
-        rf_ref[...] = jnp.zeros_like(rf_ref)
-
-    slab = slab_ref[...][0].astype(jnp.float32)   # (max_list, d)
-    sq = sq_ref[...][0]
-    sc = sc_ref[...][0]
-    ok = valid_ref[...][0]
-    mem = member_ref[...][0]
-    q = q_ref[...]                                # (b, d)
-
-    scores = 2.0 * jnp.dot(q, slab.T, preferred_element_type=jnp.float32)
-    scores = scores * sc[None, :] - sq[None, :]
-    keep = (ok > 0.5)[None, :] & (mem > 0.5)[:, None]
-    scores = jnp.where(keep, scores, NEG_INF)
-    list_id = uniq_ref[s]
-    gids = (list_id * max_list
-            + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1))
-
-    run_rv = rv_ref[...]
-    run_rf = rf_ref[...]
-    cat_v = jnp.concatenate([vals_ref[...], scores], axis=-1)
-    cat_i = jnp.concatenate([idx_ref[...], gids], axis=-1)
-    new_v, new_i, pos = _select_topk_pos(cat_v, cat_i, k)
-    vals_ref[...] = new_v.astype(vals_ref.dtype)
-    idx_ref[...] = new_i
-    rv_ref[...] = pick_rows(pos, run_rv, pv_ref[...][0], k)
-    rf_ref[...] = pick_rows(pos, run_rf, pf_ref[...][0], k)
-
-
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def ivf_score_topk_dedup_rows(grouped, grouped_sq, valid, uniq, member,
-                              queries, payload_v, payload_f, k: int, *,
-                              scales=None, interpret: bool = True):
-    """Gather-free dedup search: like ``ivf_score_topk_dedup`` but ALSO
-    returns the winners' payload rows straight from VMEM.
-
-    payload_v: (nlist, max_list, dv); payload_f: (nlist, max_list, m) —
-    grouped row-aligned with the corpus slab. Returns (vals (b, k),
-    flat_ids (b, k), rows_v (b, k, dv), rows_f (b, k, m)); unfilled (-inf)
-    slots carry zero rows (the caller substitutes its phantom-row payload).
-    """
     nlist, max_list, d = grouped.shape
     b = queries.shape[0]
     slots = uniq.shape[0]
-    dv = payload_v.shape[-1]
-    m = payload_f.shape[-1]
-    if scales is None:
-        scales = jnp.ones((nlist, max_list), jnp.float32)
-    kernel = functools.partial(_dedup_rows_kernel, k=k, max_list=max_list)
+    page = page_rows(max_list, d * grouped.dtype.itemsize)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(slots,),
-        in_specs=[
-            pl.BlockSpec((1, max_list, d), lambda s, uniq: (uniq[s], 0, 0)),
-            pl.BlockSpec((1, max_list), lambda s, uniq: (uniq[s], 0)),
-            pl.BlockSpec((1, max_list), lambda s, uniq: (uniq[s], 0)),
-            pl.BlockSpec((1, max_list), lambda s, uniq: (uniq[s], 0)),
-            pl.BlockSpec((1, b), lambda s, uniq: (s, 0)),
-            pl.BlockSpec((1, max_list, dv), lambda s, uniq: (uniq[s], 0, 0)),
-            pl.BlockSpec((1, max_list, m), lambda s, uniq: (uniq[s], 0, 0)),
-            pl.BlockSpec((b, d), lambda s, uniq: (0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((b, k), lambda s, uniq: (0, 0)),
-            pl.BlockSpec((b, k), lambda s, uniq: (0, 0)),
-            pl.BlockSpec((b, k, dv), lambda s, uniq: (0, 0, 0)),
-            pl.BlockSpec((b, k, m), lambda s, uniq: (0, 0, 0)),
-        ),
-    )
+    slab_spec = pl.BlockSpec((1, page, d), lambda s, p, uniq: (uniq[s], p, 0))
+    row_spec = pl.BlockSpec((1, 1, page), lambda s, p, uniq: (uniq[s], 0, p))
+    in_specs = [slab_spec, row_spec]
+    args = [uniq, grouped, _rows3(grouped_sq)]
+    if scales is not None:
+        in_specs.append(row_spec)
+        args.append(_rows3(scales))
+    in_specs += [row_spec,
+                 pl.BlockSpec((1, 1, b), lambda s, p, uniq: (s, 0, 0)),
+                 pl.BlockSpec((b, d), lambda s, p, uniq: (0, 0))]
+    args += [_rows3(valid), _rows3(member), queries]
+    out_spec = pl.BlockSpec((b, k), lambda s, p, uniq: (0, 0))
+    kernel = functools.partial(_dedup_kernel, k=k, max_list=max_list,
+                               page=page, has_scale=scales is not None)
+    vmem = (2 * page * d * grouped.dtype.itemsize + 2 * b * d * 4
+            + 12 * b * (k + page) * 4)
     return pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((b, k), jnp.float32),
-            jax.ShapeDtypeStruct((b, k), jnp.int32),
-            jax.ShapeDtypeStruct((b, k, dv), jnp.float32),
-            jax.ShapeDtypeStruct((b, k, m), jnp.float32),
-        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(slots, max_list // page),
+            in_specs=in_specs, out_specs=(out_spec, out_spec)),
+        out_shape=(jax.ShapeDtypeStruct((b, k), jnp.float32),
+                   jax.ShapeDtypeStruct((b, k), jnp.int32)),
+        compiler_params=compiler_params(vmem, ("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(uniq, grouped, grouped_sq, scales, valid, member, payload_v,
-      payload_f, queries)
+    )(*args)
 
 
 def dedup_probes(probes, nlist: int):
@@ -389,7 +254,7 @@ def dedup_probes(probes, nlist: int):
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def ivf_score_topk(grouped, grouped_sq, valid, probes, query, k: int, *,
-                   scales=None, interpret: bool = True):
+                   scales=None, interpret: bool):
     """Single-query probed search (batch size 1 of the batched kernel).
 
     probes: (nprobe,) int32; query: (d,). Returns (vals (k,), flat_ids (k,)).

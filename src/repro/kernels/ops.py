@@ -1,9 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels — the serving dispatch layer.
 
 Every function takes a ``use_pallas`` switch: ``True`` runs the Pallas kernel
-(natively compiled on TPU, interpret mode elsewhere), ``False`` runs the
-pure-jnp oracle from ``repro.kernels.ref``. The two paths are semantically
-identical, so every call site can be A/B-checked (see
+(compiled on TPU, interpret mode on the CPU, an error on any other backend),
+``False`` runs the pure-jnp oracle from ``repro.kernels.ref``. The two paths
+are semantically identical, so every call site can be A/B-checked (see
 ``tests/test_parity_pallas.py``).
 
 These wrappers are the *actual* serving path, not a side demo: the index
@@ -11,9 +11,9 @@ backends dispatch here when ``FCVIConfig.use_pallas`` is set —
 
   * ``score_topk``        <- ``repro.index.flat.search`` (fused distance +
     running top-k over streamed corpus blocks),
-  * ``ivf_score_topk_batch`` <- ``repro.index.ivf.search`` (scalar-prefetch
-    DMA over the grouped (nlist, max_list, d) slab layout, batched over
-    queries),
+  * ``ivf_score_topk_dedup`` <- ``repro.index.ivf.search`` (scalar-prefetch
+    DMA over the grouped (nlist, max_list, d) slab layout, probe-major over
+    the batch's deduplicated probed lists),
   * ``pq_score_batch``    <- ``repro.index.pq.search`` (one-hot-matmul ADC
     over the residual-PQ combined (coarse, code) LUT),
   * ``rescore``           <- ``repro.core.fcvi.rescore`` / ``multi_probe_query``
@@ -31,21 +31,29 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.fcvi_transform import fused_transform as _fused_transform
-from repro.kernels.fused_score_topk import (score_topk as _score_topk,
-                                            score_topk_rows as _score_topk_rows)
+from repro.kernels.fused_score_topk import score_topk as _score_topk
 from repro.kernels.rescore import rescore as _rescore
 from repro.kernels.ivf_score import (dedup_probes,
                                      ivf_score_topk as _ivf_score_topk,
                                      ivf_score_topk_batch as _ivf_score_topk_batch,
-                                     ivf_score_topk_dedup as _ivf_score_topk_dedup,
-                                     ivf_score_topk_dedup_rows as _ivf_score_topk_dedup_rows)
+                                     ivf_score_topk_dedup as _ivf_score_topk_dedup)
 from repro.kernels.pq_lut import (pq_lut_qdot as _pq_lut_qdot,
                                   pq_score as _pq_score,
                                   pq_score_batch as _pq_score_batch)
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Pallas kernels compile natively on a TPU and run in interpret mode on
+    the CPU (tests, local runs). Any other backend is an error: interpreting
+    there would hide the device behind a slow emulation."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run on 'tpu' (compiled) or 'cpu' (interpreted); "
+        f"the default backend is {backend!r}. Use use_pallas=False there.")
 
 
 def fused_transform(v, f, proj, alpha, mean_v, std_v, mean_f, std_f,
@@ -128,36 +136,6 @@ def score_topk_padded(corpus, sq_norms, queries, k, *, scales=None, mask=None,
     return vals[:nq], idx[:nq]
 
 
-def score_topk_rows_padded(corpus, sq_norms, payload_v, payload_f, queries,
-                           k, *, scales=None, use_pallas: bool = True,
-                           block_rows: int = 128, block_q: int = 64):
-    """Gather-free ``score_topk`` for arbitrary shapes: also returns the
-    winners' dequantized scan rows and payload rows straight from the
-    kernel's VMEM (see ``fused_score_topk.score_topk_rows``). Padding as in
-    ``score_topk_padded``; payload pad rows are zero."""
-    if not use_pallas:
-        return ref.ref_score_topk_rows(corpus, sq_norms, payload_v, payload_f,
-                                       queries, k, scales=scales)
-    n = corpus.shape[0]
-    nq = queries.shape[0]
-    br = min(block_rows, n)
-    bq = min(block_q, nq)
-    n_pad = -n % br
-    if n_pad:
-        payload_v = jnp.concatenate(
-            [payload_v, jnp.zeros((n_pad, payload_v.shape[1]),
-                                  payload_v.dtype)], axis=0)
-        payload_f = jnp.concatenate(
-            [payload_f, jnp.zeros((n_pad, payload_f.shape[1]),
-                                  payload_f.dtype)], axis=0)
-    corpus, sq_norms, scales, queries, _ = _pad_corpus(
-        corpus, sq_norms, scales, queries, br, bq)
-    vals, idx, srows, rv, rf = _score_topk_rows(
-        corpus, sq_norms, payload_v, payload_f, queries, k, scales=scales,
-        block_rows=br, block_q=bq, interpret=_interpret())
-    return vals[:nq], idx[:nq], srows[:nq], rv[:nq], rf[:nq]
-
-
 def rescore(cand_v, cand_f, qn, fqn, lam, *, use_pallas: bool = True,
             block_b: int = 8):
     """Candidates may arrive bf16 / int8-dequantized: both paths cast to
@@ -207,21 +185,6 @@ def ivf_score_topk_dedup(grouped, grouped_sq, valid, uniq, member, queries, k,
     return _ivf_score_topk_dedup(grouped, grouped_sq, valid, uniq, member,
                                  queries, k, scales=scales, mask=mask,
                                  interpret=_interpret())
-
-
-def ivf_score_topk_dedup_rows(grouped, grouped_sq, valid, uniq, member,
-                              queries, payload_v, payload_f, k, *,
-                              scales=None, use_pallas: bool = True):
-    """Gather-free dedup search: also returns the winners' payload rows
-    (re-rank vectors + filter values, grouped row-aligned with the corpus
-    slab) straight from the kernel's VMEM. -inf slots carry zero rows."""
-    if not use_pallas:
-        return ref.ref_ivf_score_topk_dedup_rows(
-            grouped, grouped_sq, valid > 0.5, uniq, member > 0.5, queries,
-            payload_v, payload_f, k, scales=scales)
-    return _ivf_score_topk_dedup_rows(
-        grouped, grouped_sq, valid, uniq, member, queries, payload_v,
-        payload_f, k, scales=scales, interpret=_interpret())
 
 
 def pq_score(codes, lut, *, use_pallas: bool = True, block_rows: int = 512):

@@ -1,12 +1,13 @@
 """Pallas kernel: PQ ADC scoring — LUT gather-accumulate as one-hot matmuls.
 
-scores[n] = sum_m lut[m, codes[n, m]]
+scores[q, n] = sum_m lut[q, m, codes[n, m]]
 
 GPU PQ kernels use per-lane shared-memory gathers; the TPU adaptation turns
-each subspace's gather into a (rows x ksub) one-hot times (ksub,) LUT-column
-product, which the MXU executes at full rate and which needs no dynamic
-addressing. codes stream through VMEM in row blocks; the LUT stays resident
-(M x ksub floats, a few KB).
+each subspace's gather into a (rows x ksub) one-hot times LUT product, which
+the MXU executes at full rate and which needs no dynamic addressing. codes
+stream through VMEM in row blocks; the LUTs of the whole query batch stay
+resident (q x M x ksub floats), and each block's one-hot is built once and
+shared by every query.
 """
 from __future__ import annotations
 
@@ -16,112 +17,33 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEF_BLOCK_ROWS = 512
-
-
-def _kernel(codes_ref, lut_ref, out_ref, *, ksub: int):
-    codes = codes_ref[...]            # (bn, M) int32
-    lut = lut_ref[...]                # (M, ksub)
-    bn, m = codes.shape
-    total = jnp.zeros((bn,), jnp.float32)
-    for j in range(m):                # M is small + static: unrolled
-        onehot = (codes[:, j][:, None]
-                  == jax.lax.broadcasted_iota(jnp.int32, (bn, ksub), 1))
-        total = total + jnp.dot(onehot.astype(jnp.float32), lut[j],
-                                preferred_element_type=jnp.float32)
-    out_ref[...] = total.astype(out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def pq_score(codes, lut, *, block_rows: int = DEF_BLOCK_ROWS,
-             interpret: bool = True):
-    """codes: (n, M) int32; lut: (M, ksub) f32. Returns squared dists (n,)."""
-    n, m = codes.shape
-    ksub = lut.shape[-1]
-    block_rows = min(block_rows, n)
-    if n % block_rows:
-        raise ValueError(f"n={n} must divide by block_rows={block_rows}")
-    kernel = functools.partial(_kernel, ksub=ksub)
-    return pl.pallas_call(
-        kernel,
-        grid=(n // block_rows,),
-        in_specs=[
-            pl.BlockSpec((block_rows, m), lambda i: (i, 0)),
-            pl.BlockSpec((m, ksub), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_rows,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
-        interpret=interpret,
-    )(codes, lut)
-
-
-def _qdot_kernel(q_ref, cb_ref, out_ref):
-    q = q_ref[...][:, 0, :]            # (bq, dsub)
-    cb = cb_ref[...][0]                # (ksub, dsub)
-    out = jnp.dot(q, cb.T, preferred_element_type=jnp.float32)
-    out_ref[...] = out[:, None, :].astype(out_ref.dtype)
-
-
+DEF_BATCH_BLOCK_ROWS = 256
 DEF_QDOT_BLOCK_Q = 128
 
 
-@functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
-def pq_lut_qdot(queries_sub, codebooks, *, block_q: int = DEF_QDOT_BLOCK_Q,
-                interpret: bool = True):
-    """The q . codebook cross term of PQ LUT construction as one fused matmul.
-
-    queries_sub: (q, M, dsub) queries split into subspaces; codebooks:
-    (M, ksub, dsub). Returns (q, M, ksub) with out[i, m, j] =
-    <queries_sub[i, m], codebooks[m, j]> — the dominant term of
-    ``repro.index.pq.compute_luts`` (the residual-norm and build-time terms
-    stay jnp). Grid is (query-block, subspace): each subspace's codebook
-    stays VMEM-resident while query blocks stream through the MXU. Queries
-    are zero-padded to the block multiple and sliced back off.
-    """
-    q, m, dsub = queries_sub.shape
-    ksub = codebooks.shape[1]
-    block_q = min(block_q, q)
-    pad = -q % block_q
-    if pad:
-        queries_sub = jnp.concatenate(
-            [queries_sub, jnp.zeros((pad, m, dsub), queries_sub.dtype)],
-            axis=0)
-    out = pl.pallas_call(
-        _qdot_kernel,
-        grid=((q + pad) // block_q, m),
-        in_specs=[
-            pl.BlockSpec((block_q, 1, dsub), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, ksub, dsub), lambda i, j: (j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_q, 1, ksub), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((q + pad, m, ksub), jnp.float32),
-        interpret=interpret,
-    )(queries_sub, codebooks)
-    return out[:q]
-
-
 def _batch_kernel(codes_ref, lut_ref, out_ref, *, ksub: int):
+    """One row block for every query: lut_ref (M, q, ksub) subspace-major,
+    out (q, bn). The one-hot matmul runs at HIGHEST precision: it must copy
+    fp32 LUT entries, which a single bf16 MXU pass would round."""
     codes = codes_ref[...]            # (bn, M) int32
-    lut = lut_ref[...][0]             # (M, ksub)
     bn, m = codes.shape
-    total = jnp.zeros((bn,), jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    iota = jax.lax.broadcasted_iota(jnp.int32, (bn, ksub), 1)
+    total = jnp.zeros(out_ref.shape, jnp.float32)
     for j in range(m):                # M is small + static: unrolled
-        onehot = (codes[:, j][:, None]
-                  == jax.lax.broadcasted_iota(jnp.int32, (bn, ksub), 1))
-        total = total + jnp.dot(onehot.astype(jnp.float32), lut[j],
-                                preferred_element_type=jnp.float32)
-    out_ref[...] = total[None, :].astype(out_ref.dtype)
-
-
-DEF_BATCH_BLOCK_ROWS = 256
+        onehot = (codes[:, j:j + 1] == iota).astype(jnp.float32)
+        total = total + jax.lax.dot_general(
+            lut_ref[j], onehot, (((1,), (1,)), ((), ())),
+            precision=(hi, hi), preferred_element_type=jnp.float32)
+    out_ref[...] = total
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def pq_score_batch(codes, luts, *, block_rows: int = DEF_BATCH_BLOCK_ROWS,
-                   interpret: bool = True):
+                   interpret: bool):
     """Multi-query ADC: codes (n, M) int32, luts (q, M, ksub) -> scores (q, n).
 
-    Grid is (query, row-block): each query's LUT stays resident while code
+    Grid is over row blocks: the whole batch's LUTs stay resident while code
     blocks stream through VMEM. Rows are zero-padded to a block multiple and
     the pad columns sliced off the result.
     """
@@ -136,13 +58,62 @@ def pq_score_batch(codes, luts, *, block_rows: int = DEF_BATCH_BLOCK_ROWS,
     kernel = functools.partial(_batch_kernel, ksub=ksub)
     out = pl.pallas_call(
         kernel,
-        grid=(q, n_pad // block_rows),
+        grid=(n_pad // block_rows,),
         in_specs=[
-            pl.BlockSpec((block_rows, m), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, m, ksub), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((block_rows, m), lambda j: (j, 0)),
+            pl.BlockSpec((m, q, ksub), lambda j: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_rows), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((q, block_rows), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((q, n_pad), jnp.float32),
         interpret=interpret,
-    )(codes, luts)
+    )(codes, jnp.transpose(luts, (1, 0, 2)))
     return out[:, :n]
+
+
+def pq_score(codes, lut, *, block_rows: int = DEF_BATCH_BLOCK_ROWS,
+             interpret: bool):
+    """Single-LUT ADC: codes (n, M) int32; lut (M, ksub) f32 -> squared
+    dists (n,) — a batch of one through ``pq_score_batch``."""
+    return pq_score_batch(codes, lut[None], block_rows=block_rows,
+                          interpret=interpret)[0]
+
+
+def _qdot_kernel(q_ref, cb_ref, out_ref):
+    q = q_ref[0]                       # (bq, dsub)
+    cb = cb_ref[0]                     # (ksub, dsub)
+    out_ref[0] = jnp.dot(q, cb.T, preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
+def pq_lut_qdot(queries_sub, codebooks, *, block_q: int = DEF_QDOT_BLOCK_Q,
+                interpret: bool):
+    """The q . codebook cross term of PQ LUT construction as one fused matmul.
+
+    queries_sub: (q, M, dsub) queries split into subspaces; codebooks:
+    (M, ksub, dsub). Returns (q, M, ksub) with out[i, m, j] =
+    <queries_sub[i, m], codebooks[m, j]> — the dominant term of
+    ``repro.index.pq.compute_luts`` (the residual-norm and build-time terms
+    stay jnp). Grid is (subspace, query-block): each subspace's codebook
+    stays VMEM-resident while query blocks stream through the MXU. Queries
+    are zero-padded to the block multiple and sliced back off.
+    """
+    q, m, dsub = queries_sub.shape
+    ksub = codebooks.shape[1]
+    block_q = min(block_q, q)
+    pad = -q % block_q
+    qs = jnp.transpose(queries_sub, (1, 0, 2))          # (M, q, dsub)
+    if pad:
+        qs = jnp.concatenate([qs, jnp.zeros((m, pad, dsub), qs.dtype)],
+                             axis=1)
+    out = pl.pallas_call(
+        _qdot_kernel,
+        grid=(m, (q + pad) // block_q),
+        in_specs=[
+            pl.BlockSpec((1, block_q, dsub), lambda j, i: (j, i, 0)),
+            pl.BlockSpec((1, ksub, dsub), lambda j, i: (j, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, ksub), lambda j, i: (j, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, q + pad, ksub), jnp.float32),
+        interpret=interpret,
+    )(qs, codebooks)
+    return jnp.transpose(out[:, :q], (1, 0, 2))

@@ -54,34 +54,60 @@ def ref_score_topk(corpus: Array, sq_norms: Array, queries: Array, k: int,
     return vals, ids
 
 
-def ref_score_topk_rows(corpus: Array, sq_norms: Array, payload_v: Array,
-                        payload_f: Array, queries: Array, k: int,
-                        scales=None):
-    """Oracle for the rows-returning flat kernel: top-k ids plus the
-    winners' dequantized scan rows and payload rows (gathered by id — the
-    semantic definition of what the kernel carries through VMEM)."""
-    vals, ids = ref_score_topk(corpus, sq_norms, queries, k, scales=scales)
-    scan_rows = corpus[ids].astype(jnp.float32)
-    if scales is not None:
-        scan_rows = scan_rows * scales[ids][..., None]
-    return (vals, ids, scan_rows,
-            payload_v[ids].astype(jnp.float32),
-            payload_f[ids].astype(jnp.float32))
+def rounded(product):
+    """A product rounded to fp32 before anything adds to it. The CPU
+    backend contracts a multiply and a dependent add into one fused
+    multiply-add (a single rounding) in some loop shapes and not in others,
+    so an unguarded ``x * y + z`` rounds differently from one batch size or
+    program to the next. The select keeps the multiply out of reach; a
+    non-finite product becomes NaN, which the cosine yields for it anyway."""
+    return jnp.where(jnp.abs(product) < jnp.inf, product, jnp.nan)
 
 
-def ref_rescore(cand_v: Array, cand_f: Array, qn: Array, fqn: Array, lam):
-    """Combined cosine score per candidate (Alg. 1 line 13).
+def lane_sum(x):
+    """Sum over the last axis in one fixed pairwise order: elementwise adds
+    of two halves, down to one lane. A width that is not a power of two is
+    split into its power-of-two parts (768 = 512 + 256), summed in order, so
+    nothing is padded. A plain reduction is free to pick its summation order
+    from the operand's layout and its fusion, so the same row could round
+    differently in two programs — e.g. when its rows come from an
+    all-reduce in a sharded step and from a gather in the meshless one."""
+    n = x.shape[-1]
+    top = 1 << (n.bit_length() - 1)
+    if top != n:
+        return lane_sum(x[..., :top]) + lane_sum(x[..., top:])
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return x[..., 0]
+
+
+def cosine(a, b, eps: float = 1e-8, total=lane_sum):
+    """cos(a, b) over the last axis (a and b broadcast): each product is
+    ``rounded`` and each row is summed by ``total`` (``lane_sum``'s fixed
+    order unless a caller passes another reduction). A mul+sum, not a
+    dot_general contraction: each row reduces on its own, so a candidate's
+    score does not depend on its k-position or the tile width (a
+    contraction's CPU lowering treats main-loop vs remainder k-rows
+    differently, and the routed path re-scores the same candidate at a
+    different k' than the dense path)."""
+    num = total(rounded(a * b))
+    nn = jnp.sqrt(total(rounded(a * a))) * jnp.sqrt(total(rounded(b * b)))
+    return num / (rounded(nn) + eps)
+
+
+def ref_rescore(cand_v, cand_f, qn, fqn, lam, total=lane_sum):
+    """lam * cos(v, q) + (1 - lam) * cos(f, F_q) per candidate (Alg. 1 line
+    13): the one definition that the XLA re-rank (``fcvi.combined_score``)
+    and the Pallas kernel (``rescore.py``, whose body this is) share. The
+    kernel passes the native lane reduction as ``total``
+    (``rescore.py`` says why), so its sums may round differently.
 
     cand_v: (b, kp, d); cand_f: (b, kp, m); qn: (b, d); fqn: (b, m).
     """
-    def cos(a, b):
-        num = jnp.sum(a * b, axis=-1)
-        den = (jnp.linalg.norm(a, axis=-1) * jnp.linalg.norm(b, axis=-1) + 1e-8)
-        return num / den
-
-    s_v = cos(cand_v, qn[:, None, :])
-    s_f = cos(cand_f, fqn[:, None, :])
-    return lam * s_v + (1.0 - lam) * s_f
+    return (rounded(lam * cosine(cand_v, qn[:, None, :], total=total))
+            + rounded((1.0 - lam) * cosine(cand_f, fqn[:, None, :],
+                                           total=total)))
 
 
 def ref_ivf_score_topk(grouped: Array, grouped_sq: Array, valid: Array,
@@ -171,27 +197,6 @@ def ref_ivf_score_topk_dedup(grouped: Array, grouped_sq: Array, valid: Array,
     vals, pos = jax.lax.top_k(s, k)
     ids = flat_ids[pos]
     return vals, jnp.where(jnp.isneginf(vals), 0, ids)
-
-
-def ref_ivf_score_topk_dedup_rows(grouped: Array, grouped_sq: Array,
-                                  valid: Array, uniq: Array, member: Array,
-                                  queries: Array, payload_v: Array,
-                                  payload_f: Array, k: int, scales=None):
-    """Oracle for the rows-returning dedup kernel: payload rows gathered by
-    the winning flat ids; unfilled (-inf) slots carry ZERO rows, matching
-    the kernel's init state for never-written output slots."""
-    s, flat_ids = _dedup_scores(grouped, grouped_sq, valid, uniq, member,
-                                queries, scales=scales)
-    vals, pos = jax.lax.top_k(s, k)
-    ids = flat_ids[pos]
-    dv = payload_v.shape[-1]
-    m = payload_f.shape[-1]
-    rows_v = payload_v.reshape(-1, dv)[ids].astype(jnp.float32)
-    rows_f = payload_f.reshape(-1, m)[ids].astype(jnp.float32)
-    dead = jnp.isneginf(vals)
-    rows_v = jnp.where(dead[..., None], 0.0, rows_v)
-    rows_f = jnp.where(dead[..., None], 0.0, rows_f)
-    return (vals, jnp.where(dead, 0, ids), rows_v, rows_f)
 
 
 def ref_pq_lut_qdot(queries_sub: Array, codebooks: Array) -> Array:

@@ -2,10 +2,6 @@
 
 Defined as functions (not module constants) so importing this module never
 touches jax device state — the dry-run sets XLA_FLAGS before first jax init.
-
-``make_mesh`` papers over the jax API skew: ``axis_types`` (and
-``jax.sharding.AxisType``) only exist from jax 0.5; on older releases every
-mesh axis is implicitly Auto, so the kwarg is simply dropped.
 """
 from __future__ import annotations
 
@@ -16,11 +12,9 @@ from jax.sharding import Mesh
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """Version-compatible ``jax.make_mesh`` with all-Auto axis types."""
-    if hasattr(jax.sharding, "AxisType"):
-        types = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=types)
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with all-Auto axis types."""
+    types = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=types)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

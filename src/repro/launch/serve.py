@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.core import FCVIConfig, build, ground_truth_combined, recall_at_k
 from repro.data.synthetic import CorpusSpec, make_corpus, sample_queries
+from repro.launch.cache import enable_compile_cache
 from repro.serve.engine import EngineConfig, FCVIEngine
 
 
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--alpha", type=float, default=1.0)
     ap.add_argument("--lam", type=float, default=0.6)
     args = ap.parse_args()
+    enable_compile_cache()
 
     spec = CorpusSpec(n=args.n, d=args.d, n_categories=6, n_numeric=2, seed=0)
     corpus = make_corpus(spec)

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
-
 from repro.distributed.sharding import AxisRules, param_spec_tree
 from repro.models import model as M
 from repro.models.layers import COMPUTE_DTYPE
@@ -403,7 +401,7 @@ def build_fcvi_cell(shape: str, mesh, extra_rules: Optional[dict] = None,
                 stride = n_loc
                 for ax in reversed(corpus_axes):
                     offset = offset + jax.lax.axis_index(ax) * stride
-                    stride = stride * axis_size(ax)
+                    stride = stride * jax.lax.axis_size(ax)
                 gidx = gidx + offset
                 from repro.index.distributed import _merge_over_axis
                 for i, ax in enumerate(reversed(corpus_axes)):
@@ -412,7 +410,7 @@ def build_fcvi_cell(shape: str, mesh, extra_rules: Optional[dict] = None,
                     vals, gidx = _merge_over_axis(vals, gidx, ax, keep)
                 return vals, gidx
 
-            _, cand = shard_map(
+            _, cand = jax.shard_map(
                 local, mesh=mesh,
                 in_specs=(P2(corpus_axes), P2(corpus_axes), P2(corpus_axes)),
                 out_specs=(P2(), P2()), check_vma=False)(
@@ -428,7 +426,7 @@ def build_fcvi_cell(shape: str, mesh, extra_rules: Optional[dict] = None,
                     stride = n_loc2
                     for ax in reversed(corpus_axes):
                         offset = offset + jax.lax.axis_index(ax) * stride
-                        stride = stride * axis_size(ax)
+                        stride = stride * jax.lax.axis_size(ax)
                     lid = cand - offset
                     own = (lid >= 0) & (lid < n_loc2)
                     safe = jnp.clip(lid, 0, n_loc2 - 1)
@@ -447,7 +445,7 @@ def build_fcvi_cell(shape: str, mesh, extra_rules: Optional[dict] = None,
                     return (lam * nv / (dv * qn + 1e-8)
                             + (1 - lam) * nf / (df * fqn + 1e-8))
 
-                score = shard_map(
+                score = jax.shard_map(
                     rescore, mesh=mesh,
                     in_specs=(P2(corpus_axes), P2(corpus_axes)),
                     out_specs=P2(), check_vma=False)(vectors_n, filters_n)

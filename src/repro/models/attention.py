@@ -20,8 +20,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
-
 from repro.distributed.sharding import shard_act
 from repro.models.layers import COMPUTE_DTYPE, _normal, apply_rope, softcap
 
@@ -148,7 +146,7 @@ def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
                         q_chunk=min(q_chunk, s_loc), kv_chunk=kv_chunk,
                         _no_seq_shard=True)
 
-                return shard_map(
+                return jax.shard_map(
                     local, mesh=r.mesh,
                     in_specs=(P(dp, ax), P(dp), P(dp)),
                     out_specs=P(dp, ax), check_vma=False)(q, k, v)

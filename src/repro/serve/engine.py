@@ -144,61 +144,6 @@ def _batch_step(index: FCVIIndex, delta_vn, delta_fn, delta_flat, q, f,
     return scores, ids, margin
 
 
-@partial(jax.jit, static_argnames=("k", "kp", "kd"))
-def _batch_step_rows(index: FCVIIndex, delta_vn, delta_fn, delta_flat,
-                     grouped_pv, grouped_pf, q, f, *, k: int, kp: int,
-                     kd: int):
-    """Gather-free variant of ``_batch_step`` (flat/IVF backends).
-
-    Candidate generation goes through the rows-returning search entry points
-    (``flat.search_rows`` / ``ivf.search_rows``): the winners' re-rank rows
-    come straight out of the scoring kernel's VMEM instead of a second
-    (b, k') HBM gather from ``vectors_n``/``filters_n``. ``grouped_pv``/
-    ``grouped_pf`` are the IVF grouped payload slabs (None for flat — the
-    flat payload IS ``vectors_n``/``filters_n`` in corpus order). Results
-    are bit-identical to ``_batch_step``: carried rows equal the gathered
-    rows bitwise, and unfilled (-inf) slots carry corpus row 0's payload,
-    matching the id-0 gather convention.
-    """
-    _TRACE_COUNT[0] += 1
-    cfg = index.config
-    qn, fqn = index.transform.normalize(q, f)
-    q_t = index.transform.apply_normalized(qn, fqn, use_pallas=cfg.use_pallas)
-    if cfg.backend == "ivf":
-        _, cand, rv, rf = index.backend.search_rows(
-            q_t, kp, index.vectors_n, index.filters_n,
-            grouped_pv=grouped_pv, grouped_pf=grouped_pf,
-            nprobe=cfg.nprobe, use_pallas=cfg.use_pallas)
-    else:
-        _, cand, rv, rf = index.backend.search_rows(
-            q_t, kp, index.vectors_n, index.filters_n,
-            use_pallas=cfg.use_pallas)
-    score = fcvi.combined_score(rv, rf, qn, fqn, cfg.lam,
-                                use_pallas=cfg.use_pallas)
-    scores, pos = jax.lax.top_k(score, k)
-    ids = jnp.take_along_axis(cand, pos, axis=-1)
-
-    if delta_flat is not None:
-        nd = delta_vn.shape[0]
-        if kd < nd:
-            _, dcand, drv, drf = flat_mod.search_rows(
-                delta_flat, q_t, kd, delta_vn, delta_fn,
-                use_pallas=cfg.use_pallas)
-        else:
-            dcand = jnp.broadcast_to(jnp.arange(nd)[None, :],
-                                     (q.shape[0], nd))
-            drv, drf = delta_vn[dcand], delta_fn[dcand]
-        s = fcvi.combined_score(drv, drf, qn, fqn, cfg.lam,
-                                use_pallas=cfg.use_pallas)
-        dvals, dpos = jax.lax.top_k(s, min(k, kd))
-        dids = index.size + jnp.take_along_axis(dcand, dpos, axis=-1)
-        scores, ids = flat_mod.merge_topk(scores, ids, dvals,
-                                          dids.astype(ids.dtype), k)
-
-    margin = scores[:, 0] - scores[:, -1]
-    return scores, ids, margin
-
-
 # ---------------------------------------------------------------------------
 # Predicate-filtered physical plans (general filter algebra, meshless side).
 #
@@ -315,11 +260,11 @@ class EngineConfig:
     compact_threshold: int = 2048  # delta rows triggering compaction
     multi_probe_r: int = 4
     router_nprobe: int = 0         # routed flat serving: probed psi-clusters
-    # gather-free re-rank: candidate generation emits the winners' re-rank
-    # ROWS (from VMEM / shard-local payloads) instead of ids that a second
-    # HBM gather (single-device) or mask+psum distributed gather (sharded)
-    # must resolve. Results are bit-identical either way; False keeps the
-    # legacy id-gather step (flat/IVF only; PQ single-device always gathers)
+    # gather-free re-rank (mesh-sharded step only): each shard reads its
+    # winners' re-rank rows from its LOCAL payload block and the merge
+    # carries finished scores, instead of a mask+psum distributed gather
+    # after the merge. Results are bit-identical either way; a meshless
+    # engine has one step and ignores the setting
     gather_free: bool = True
     # -- resilience envelope (off-trace; defaults keep behavior unchanged) --
     deadline_s: float = 0.0        # per-batch deadline; 0 disables the check
@@ -460,7 +405,6 @@ class FCVIEngine:
         self._delta_f: list = []
         self._delta: Optional[_DeltaBuffer] = None
         self._mesh, self._rules, self._placement = mesh, rules, placement
-        self._grouped_payload = None  # IVF gather-free payload slabs (lazy)
         # predicate-filtered serving state: the RAW attribute table (defaults
         # to the de-normalized filter columns the index was built from), its
         # column names, and the selectivity-aware query planner
@@ -1096,37 +1040,13 @@ class FCVIEngine:
             self._sharded_delta = self._sharded.shard_delta(self._delta)
         return self._sharded_delta
 
-    def _rows_payload(self):
-        """IVF gather-free payload slabs (lazy): the re-rank originals
-        ``vectors_n``/``filters_n`` regrouped into (nlist, max_list, dim)
-        list order, so the dedup rows-kernel can emit the winners' re-rank
-        rows straight from its scan. Flat needs no extra payload — corpus
-        order IS slab order — so it returns (None, None). Invalidated on
-        ``compact()``/``heal()`` (the only events that change the corpus)."""
-        if self.index.config.backend != "ivf":
-            return None, None
-        if self._grouped_payload is None:
-            from repro.index import ivf as ivf_mod
-            lists = self.index.backend.lists
-            self._grouped_payload = (
-                ivf_mod.build_grouped_payload(self.index.vectors_n, lists),
-                ivf_mod.build_grouped_payload(self.index.filters_n, lists))
-        return self._grouped_payload
-
     def _step(self, dvn, dfn, dflat, q, f, *, k: int, kp: int, kd: int,
               alive=None):
         """Dispatch one padded batch to the single-device jitted step or the
         mesh-sharded DENSE shard_map step (identical results by
         construction; the routed step is dispatched by ``_run_batch``).
-        ``cfg.gather_free`` picks the rows-carrying step variant for the
-        flat/IVF backends (PQ re-ranks from reconstructed originals and
-        keeps the id-gather step)."""
+        ``cfg.gather_free`` picks the sharded step's re-rank variant."""
         if self._sharded is None:
-            if (self.cfg.gather_free
-                    and self.index.config.backend in ("flat", "ivf")):
-                gpv, gpf = self._rows_payload()
-                return _batch_step_rows(self.index, dvn, dfn, dflat,
-                                        gpv, gpf, q, f, k=k, kp=kp, kd=kd)
             return _batch_step(self.index, dvn, dfn, dflat, q, f,
                                k=k, kp=kp, kd=kd)
         return self._sharded.step(self._sharded_delta_view(dflat), q, f,
@@ -1202,7 +1122,6 @@ class FCVIEngine:
         self._delta_v, self._delta_f = [], []
         self._delta = None
         self._sharded_delta = None
-        self._grouped_payload = None  # corpus changed: payload slabs stale
         self._router_centers = None  # corpus changed: re-derive the router
         if self._sharded is not None:
             self._build_sharded()   # re-shard the grown slabs onto the mesh
@@ -1274,7 +1193,6 @@ class FCVIEngine:
             self._delta_v = cand._delta_v
             self._delta_f = cand._delta_f
             self._delta = cand._delta
-            self._grouped_payload = None
             self.health = ShardHealth(self._sharded.n_shards,
                                       straggler_z=self.cfg.straggler_z)
             self._alive_sig = None

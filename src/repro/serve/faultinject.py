@@ -102,20 +102,19 @@ def surviving_reference(engine):
         sq = jnp.where(jnp.asarray(mask), b.sq_norms, jnp.inf)
         backend = dataclasses.replace(b, sq_norms=sq)
     elif idx.config.backend == "ivf":
-        from repro.index.slab import build_grouped
-
         l2s = np.asarray(engine._sharded.slab.list_to_shard)
         dead_list = ~engine.health.alive_mask()[l2s]
         lists = np.asarray(b.lists).copy()
         sizes = np.asarray(b.list_sizes).copy()
         lists[dead_list] = -1          # empty the dead shards' lists;
         sizes[dead_list] = 0           # centroids stay (probe selection
-        lists_j = jnp.asarray(lists)   # must match the degraded step's)
-        grouped, grouped_sq, valid = build_grouped(
-            b.vectors, b.sq_norms, lists_j)
+                                       # must match the degraded step's)
+        # invalid slots score -inf whatever the slab holds there, so the
+        # grouped slab is shared with the index rather than rebuilt
+        valid = jnp.where(jnp.asarray(dead_list)[:, None], 0.0, b.valid)
         backend = dataclasses.replace(
-            b, lists=lists_j, list_sizes=jnp.asarray(sizes),
-            grouped=grouped, grouped_sq=grouped_sq, valid=valid)
+            b, lists=jnp.asarray(lists), list_sizes=jnp.asarray(sizes),
+            valid=valid)
     else:
         raise NotImplementedError(
             f"surviving_reference: backend {idx.config.backend!r}")

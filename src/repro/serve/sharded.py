@@ -70,7 +70,6 @@ import numpy as np
 
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import fcvi
 from repro.index import flat as flat_mod
 from repro.index import pq as pq_mod
@@ -437,23 +436,17 @@ class ShardedServing:
         idx = self.index
         rep = NamedSharding(self.mesh, P())
         backend = idx.config.backend
-        if backend == "flat":
-            ids = jnp.asarray(np.asarray(self.slab.row_ids))
-            if (self.placement == "contiguous"
+        if backend in ("flat", "ivf"):
+            # flat: (n_pad,) slab row ids; IVF: (slots, max_list) lists
+            ids = np.asarray(self.slab.row_ids if backend == "flat"
+                             else self.slab.lists)
+            if (self.placement == "contiguous" and backend == "flat"
                     and ids.shape[0] == self.vectors_n.shape[0]):
                 pv, pf = self.vectors_n, self.filters_n
             else:
-                keep = (ids >= 0)[:, None]
-                safe = jnp.maximum(ids, 0)
-                pv = self._put_rows(jnp.where(keep, idx.vectors_n[safe], 0.0))
-                pf = self._put_rows(jnp.where(keep, idx.filters_n[safe], 0.0))
-        elif backend == "ivf":
-            from repro.index import ivf as ivf_mod
-            lists = jnp.asarray(np.asarray(self.slab.lists))
-            pv = self._put_rows(
-                ivf_mod.build_grouped_payload(idx.vectors_n, lists))
-            pf = self._put_rows(
-                ivf_mod.build_grouped_payload(idx.filters_n, lists))
+                pv, pf = (slab_mod.put_gathered(self.mesh, self.axes, x, ids,
+                                                0.0)
+                          for x in (idx.vectors_n, idx.filters_n))
         else:   # pq: contiguous rows — the sharded originals alias directly
             pv, pf = self.vectors_n, self.filters_n
         vn0 = jax.device_put(idx.vectors_n[0], rep)
@@ -956,8 +949,8 @@ class ShardedServing:
         if degraded:
             specs = specs + (P(),)     # alive mask: replicated, traced
         n_out = (5 if routed else 3) + (1 if degraded else 0)
-        mapped = shard_map(body, mesh=self.mesh, in_specs=specs,
-                           out_specs=(P(),) * n_out, check_vma=False)
+        mapped = jax.shard_map(body, mesh=self.mesh, in_specs=specs,
+                               out_specs=(P(),) * n_out, check_vma=False)
         return jax.jit(mapped)
 
     # -- the sharded filtered (predicate) step ----------------------------
@@ -1064,7 +1057,7 @@ class ShardedServing:
 
         row = P(axes)
         n_in = 4 if has_scales else 3
-        mapped = shard_map(
+        mapped = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(row,) * n_in + (P(),) * 5,
             out_specs=(P(axes), P(axes)), check_vma=False)

@@ -1,13 +1,13 @@
 """Gather-free re-rank parity: carried rows/scores vs the HBM/psum gather.
 
-The contract: ``EngineConfig.gather_free`` is a pure EXECUTION knob. On a
-single device the scan kernels return the winners' re-rank rows straight
-from VMEM (no HBM id-gather before rescoring); under ``shard_map`` each
-shard gathers its own winners from its LOCAL payload block, rescores them in
-place, and the cross-shard merge carries finished scores instead of
-psum-gathering rows afterwards. Both variants must return top-k ids and
-scores IDENTICAL to the gather-based step — flat, IVF and PQ, kernels on and
-off, fp32 and int8 storage, with a live delta buffer, dense and routed.
+The contract: ``EngineConfig.gather_free`` is a pure EXECUTION knob of the
+mesh-sharded step (a meshless engine has one step and ignores it). Under
+``shard_map`` each shard gathers its own winners from its LOCAL payload
+block, rescores them in place, and the cross-shard merge carries finished
+scores instead of psum-gathering rows afterwards. It must return top-k ids
+and scores IDENTICAL to the meshless gather-based step and to the psum step
+— flat, IVF and PQ, kernels on and off, fp32 and int8 storage, with a live
+delta buffer, dense and routed.
 The collective-free property itself is pinned by
 ``tests/test_hlo_analysis.py::test_gather_free_step_has_no_all_reduce``.
 """
@@ -68,11 +68,13 @@ def _assert_identical(a, b):
 @pytest.mark.parametrize("storage", ["float32", "int8"])
 def test_single_device_gather_free_identity(data, backend, use_pallas,
                                             storage):
-    """Meshless: the rows-returning scan variants must reproduce the
-    gather-based step bit-for-bit, including through a live delta buffer."""
+    """The gather-free shard_map step on a 1-device mesh must reproduce the
+    meshless gather-based step bit-for-bit, kernels on and off, fp32 and
+    int8, including through a live delta buffer."""
     corpus, q, fq = data
+    mesh = make_mesh((1, 1), ("data", "model"))
     e0 = _engine(corpus, backend, use_pallas, False, storage)
-    e1 = _engine(corpus, backend, use_pallas, True, storage)
+    e1 = _engine(corpus, backend, use_pallas, True, storage, mesh=mesh)
     _assert_identical(e0.search(q, fq), e1.search(q, fq))
     r = np.random.default_rng(0)
     nv = r.normal(size=(20, corpus.spec.d)).astype(np.float32)
