@@ -13,8 +13,7 @@ and corpus.
 Writes BENCH_query_path.json next to this file:
 
   {"results": [{backend, use_pallas, storage_dtype, batch, qps,
-                ms_per_query, bytes_per_query, effective_bandwidth_gbps,
-                recall_vs_fp32}, ...],
+                ms_per_query, recall_vs_fp32}, ...],
    "routed": [{backend, routing, filter_mix, qps, shard_skip_rate,
                router_fallback_frac}, ...],
    "filtered": [{backend, filter_mix, plan, est_selectivity, qps,
@@ -22,10 +21,7 @@ Writes BENCH_query_path.json next to this file:
    "legacy": {...}, "speedup_batch64_flat_vs_legacy": ...,
    "speedup_batch64_flat_vs_pr1_jnp": ...}
 
-``bytes_per_query`` is the engine's modeled HBM scan traffic (flat: the
-whole slab; IVF: the probed fraction; PQ: the code matrix) divided by
-served queries — the number that makes the fp32 -> bf16 -> int8 storage
-ladder visible. ``recall_vs_fp32`` compares each reduced-precision row's
+``recall_vs_fp32`` compares each reduced-precision row's
 final top-k ids against the fp32 row of the same config (1.0 = the
 exact-refine pass fully recovered the fp32 ranking).
 
@@ -278,16 +274,11 @@ def main():
 
         _, ids = run(q, fq)                    # warmup (jit compile)
         ids = np.asarray(ids)
-        eng.stats = type(eng.stats)()          # count timed runs only
         t = time_search(run, q, fq, args.iters)
-        st = eng.stats
         row = dict(backend=backend, use_pallas=use_pallas,
                    storage_dtype=storage_dtype, batch=batch,
                    mesh_devices=mesh_devices,
-                   qps=batch / t, ms_per_query=1e3 * t / batch,
-                   bytes_per_query=round(st.bytes_per_query),
-                   effective_bandwidth_gbps=round(
-                       st.effective_bandwidth_gbps, 3))
+                   qps=batch / t, ms_per_query=1e3 * t / batch)
         key = (backend, use_pallas, batch)
         if storage_dtype == "float32" and mesh_devices == 0:
             fp32_ids[key] = ids
@@ -299,8 +290,7 @@ def main():
         print(f"{backend:4s} pallas={int(use_pallas)} "
               f"st={storage_dtype:8s} batch={batch:3d} "
               f"mesh={mesh_devices} "
-              f"qps={row['qps']:9.1f}  {row['ms_per_query']:.3f} ms/q  "
-              f"{row['bytes_per_query']/1e3:.0f} KB/q"
+              f"qps={row['qps']:9.1f}  {row['ms_per_query']:.3f} ms/q"
               + (f"  recall={row['recall_vs_fp32']:.3f}"
                  if "recall_vs_fp32" in row else ""))
 
@@ -458,11 +448,8 @@ def main():
             host_devices=ndev,
             note=("use_pallas rows run the Pallas kernels in interpret mode "
                   "on non-TPU hosts (dispatch correctness, not TPU perf); "
-                  "bytes_per_query / effective_bandwidth_gbps are the "
-                  "engine's MODELED HBM scan traffic (slab array sizes x "
-                  "probed fraction) per served query — bf16 halves and int8 "
-                  "quarters the scanned bytes vs fp32, with recall_vs_fp32 "
-                  "= 1.0 after the exact-refine pass; "
+                  "recall_vs_fp32 compares reduced-precision final ids with "
+                  "the fp32 row (1.0 after the exact-refine pass); "
                   "the engine batch step is one jax.jit-compiled function; "
                   "mesh_devices>0 rows run the shard_map sharded step — "
                   "forced host devices share cores, so those rows measure "

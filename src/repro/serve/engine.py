@@ -18,6 +18,10 @@ over statically padded batch shapes: a batch costs a single dispatch, not a
 Python re-entry per stage. Cache lookups, stats, and the escalation decision
 are host-side bookkeeping OFF the traced path; ``trace_count()`` exposes the
 compile counter so tests can pin down per-batch retracing regressions.
+Host spans (``repro.serve.spans``) mark each call's layers in any profiler
+trace taken around serving: ``fcvi.search`` > ``fcvi.validate``,
+``fcvi.cache``, ``fcvi.batch`` > ``fcvi.step``, ``fcvi.escalate``,
+``fcvi.fetch``.
 
 When ``FCVIConfig.use_pallas`` is set on the wrapped index, everything inside
 the step — the fused query transform, candidate generation, re-scoring, and
@@ -90,6 +94,7 @@ from repro.serve.health import (BackpressureError, ShardHealth,
 from repro.serve.planner import (CANDIDATE_PAD, PLAN_FOLD, PLAN_MASK,
                                  PLAN_ROUTED, PLANS, QueryPlanner,
                                  _pow2_at_least)
+from repro.serve.spans import span
 
 # magnitudes beyond this overflow fp32 when squared in the scoring path —
 # the input-hardening boundary rejects them as out of support
@@ -103,6 +108,15 @@ _TRACE_COUNT = [0]
 def trace_count() -> int:
     """How many times the jitted engine batch step has been (re)traced."""
     return _TRACE_COUNT[0]
+
+
+def _subbatch_rows(n: int, b: int) -> int:
+    """Rows of the sub-batch that re-runs ``n`` rows of a padded batch of
+    ``b``: ``b`` halved while the half still holds them (a power-of-two
+    bucket when ``b`` is one), so that each bucket is traced once."""
+    while b // 2 >= max(n, 1):
+        b //= 2
+    return b
 
 
 @partial(jax.jit, static_argnames=("k", "kp", "kd"))
@@ -292,18 +306,13 @@ class EngineStats:
     escalations: int = 0
     inserts: int = 0
     compactions: int = 0
-    total_time_s: float = 0.0
+    # rows the stage-2 escalation sub-batches ran, power-of-two padding
+    # included: ``escalations / escalation_rows`` is their useful share
+    escalation_rows: int = 0
     routed_batches: int = 0
     router_fallbacks: int = 0
     shards_active: int = 0
     shard_steps: int = 0
-    # -- storage-bandwidth accounting (off-trace, model-based) ------------
-    # HBM bytes the candidate-generation scans streamed, modeled per batch
-    # from the index's slab array sizes (flat: the whole slab; IVF: the
-    # probed fraction; PQ: the code matrix) — what makes the fp32 -> bf16 ->
-    # int8 storage ladder visible as a served-bytes number
-    bytes_scanned: int = 0
-    scan_batches: int = 0          # batches the bytes model accounted
     # -- degraded serving / resilience envelope ---------------------------
     degraded_batches: int = 0      # batches served with >= 1 dead shard
     uncovered_queries: int = 0     # queries whose coverage flag was raised
@@ -321,25 +330,6 @@ class EngineStats:
     # per-query coverage flags of the LAST search call (True = certified
     # unaffected by dead shards; all-True while healthy)
     last_coverage: Optional[np.ndarray] = None
-
-    @property
-    def qps(self) -> float:
-        return self.queries / self.total_time_s if self.total_time_s else 0.0
-
-    @property
-    def bytes_per_query(self) -> float:
-        """Modeled scan bytes per served query (cache hits included in the
-        denominator — they stream nothing, which is the point of the cache)."""
-        return self.bytes_scanned / self.queries if self.queries else 0.0
-
-    @property
-    def effective_bandwidth_gbps(self) -> float:
-        """Modeled scan bytes / serving wall time, in GB/s: how fast the
-        engine streams index storage. Rises along the storage-dtype ladder
-        only if the qps gain matches the bytes drop."""
-        if not self.total_time_s:
-            return 0.0
-        return self.bytes_scanned / self.total_time_s / 1e9
 
     @property
     def shard_skip_rate(self) -> float:
@@ -515,39 +505,6 @@ class FCVIEngine:
         while len(self._cache) > self.cfg.cache_entries:
             self._cache.popitem(last=False)
 
-    # -- storage-bandwidth accounting (off-trace model) --------------------
-    def _batch_scan_bytes(self, b: int) -> int:
-        """Modeled HBM bytes candidate generation streams for one padded
-        batch of ``b`` queries: flat scans the whole slab (vectors + norms +
-        int8 scales), IVF streams the probed fraction of the grouped slabs
-        (dedup-capped at nlist), PQ sweeps the code matrix; a pending delta
-        adds its flat slab. Off-trace and model-based — it counts the bytes
-        the scan semantically reads, which is what the storage-dtype ladder
-        changes — so the hot path stays untouched."""
-        be = self.index.backend
-        cfg = self.index.config
-        if cfg.backend == "flat":
-            n = be.vectors.nbytes + be.sq_norms.nbytes
-            if be.scales is not None:
-                n += be.scales.nbytes
-        elif cfg.backend == "ivf":
-            slab = be.grouped.nbytes + be.grouped_sq.nbytes
-            if be.grouped_scales is not None:
-                n = slab + be.grouped_scales.nbytes
-            else:
-                n = slab
-            nlist = be.nlist
-            probed = min(b * min(cfg.nprobe, nlist), nlist)
-            n = (n * probed) // nlist + be.centroids.nbytes
-        else:
-            n = be.codes.nbytes + be.coarse_ids.nbytes
-        delta = self._delta
-        if delta is not None:
-            n += delta.flat.vectors.nbytes + delta.flat.sq_norms.nbytes
-            if delta.flat.scales is not None:
-                n += delta.flat.scales.nbytes
-        return int(n)
-
     # -- input hardening ---------------------------------------------------
     def _validate_inputs(self, queries, filters):
         """Reject malformed/poisoned inputs at the serving boundary with
@@ -642,22 +599,35 @@ class FCVIEngine:
         bit-identical to a search over the surviving shards' rows and
         ``stats.last_coverage`` flags the queries the dead shards could have
         affected. Raises ``BackpressureError`` when the cache-miss queue
-        exceeds ``cfg.queue_budget`` (> 0)."""
-        if filter is not None:
-            if filters is not None:
+        exceeds ``cfg.queue_budget`` (> 0).
+
+        Each call opens the host span ``fcvi.search`` (``repro.serve.spans``),
+        with ``fcvi.validate``, ``fcvi.cache`` and one ``fcvi.batch`` per
+        padded batch inside it in similarity mode."""
+        mode = "similarity" if filter is None else "predicate"
+        with span("fcvi.search", mode=mode) as sp:
+            if filter is not None:
+                if filters is not None:
+                    raise ValueError(
+                        "pass either filters= (similarity mode) or filter= "
+                        "(predicate mode), not both")
+                return self._search_filtered(queries, filter, sp, plan=plan)
+            if filters is None:
+                raise TypeError(
+                    "search() needs filters= (similarity mode) or filter= "
+                    "(predicate mode)")
+            if plan is not None:
                 raise ValueError(
-                    "pass either filters= (similarity mode) or filter= "
-                    "(predicate mode), not both")
-            return self._search_filtered(queries, filter, plan=plan)
-        if filters is None:
-            raise TypeError(
-                "search() needs filters= (similarity mode) or filter= "
-                "(predicate mode)")
-        if plan is not None:
-            raise ValueError("plan= only applies to predicate mode (filter=)")
-        queries, filters = self._validate_inputs(queries, filters)
-        t0 = time.perf_counter()
+                    "plan= only applies to predicate mode (filter=)")
+            return self._search_similarity(queries, filters, sp)
+
+    def _search_similarity(self, queries, filters, sp):
+        """Similarity mode of ``search``; ``sp`` is its ``fcvi.search``
+        span."""
+        with span("fcvi.validate"):
+            queries, filters = self._validate_inputs(queries, filters)
         n = queries.shape[0]
+        sp.set_metadata(queries=n)
         k = self.cfg.k
         out_scores = np.zeros((n, k), np.float32)
         out_ids = np.zeros((n, k), np.int64)
@@ -665,15 +635,17 @@ class FCVIEngine:
         alive = self._alive_for_search()
         use_cache = alive is None
 
-        keys = self._cache_keys(queries, filters)
-        todo = []
-        for i, key in enumerate(keys):
-            hit = self._cache_get(key) if use_cache else None
-            if hit is not None:
-                out_scores[i], out_ids[i] = hit
-                self.stats.cache_hits += 1
-            else:
-                todo.append(i)
+        with span("fcvi.cache") as cache_span:
+            keys = self._cache_keys(queries, filters)
+            todo = []
+            for i, key in enumerate(keys):
+                hit = self._cache_get(key) if use_cache else None
+                if hit is not None:
+                    out_scores[i], out_ids[i] = hit
+                    self.stats.cache_hits += 1
+                else:
+                    todo.append(i)
+            cache_span.set_metadata(hits=n - len(todo))
 
         if self.cfg.queue_budget and len(todo) > self.cfg.queue_budget:
             self.stats.backpressure_drops += len(todo)
@@ -691,44 +663,45 @@ class FCVIEngine:
         bs = self.cfg.batch_size
         for s in range(0, len(todo), bs):
             idxs = todo[s:s + bs]
-            pad = bs - len(idxs)
-            if pad and self._routed:
-                # pad with the last real query (not zeros): pad rows then
-                # route like an existing query instead of activating
-                # whatever shards the zero vector happens to map to
-                pq, pf = queries[idxs[-1:]], filters[idxs[-1:]]
-                q = np.concatenate([queries[idxs], np.repeat(pq, pad, 0)])
-                f = np.concatenate([filters[idxs], np.repeat(pf, pad, 0)])
-            else:
-                q = np.concatenate(
-                    [queries[idxs],
-                     np.zeros((pad, queries.shape[1]), np.float32)])
-                f = np.concatenate(
-                    [filters[idxs],
-                     np.zeros((pad, filters.shape[1]), np.float32)])
-            qj, fj = jnp.asarray(q), jnp.asarray(f)
-            scores, ids, covered = self._dispatch_batch(
-                qj, fj, k, n_real=len(idxs), alive=alive)
-            self.stats.bytes_scanned += self._batch_scan_bytes(bs)
-            self.stats.scan_batches += 1
-            scores, ids = np.asarray(scores), np.asarray(ids)
-            for j, i in enumerate(idxs):
-                out_scores[i], out_ids[i] = scores[j], ids[j]
-                if covered is not None:
-                    coverage[i] = covered[j]
-                if use_cache:
-                    self._cache_put(keys[i], (scores[j], ids[j]))
+            with span("fcvi.batch", rows=bs, real=len(idxs)):
+                pad = bs - len(idxs)
+                if pad and self._routed:
+                    # pad with the last real query (not zeros): pad rows then
+                    # route like an existing query instead of activating
+                    # whatever shards the zero vector happens to map to
+                    pq, pf = queries[idxs[-1:]], filters[idxs[-1:]]
+                    q = np.concatenate([queries[idxs], np.repeat(pq, pad, 0)])
+                    f = np.concatenate([filters[idxs], np.repeat(pf, pad, 0)])
+                else:
+                    q = np.concatenate(
+                        [queries[idxs],
+                         np.zeros((pad, queries.shape[1]), np.float32)])
+                    f = np.concatenate(
+                        [filters[idxs],
+                         np.zeros((pad, filters.shape[1]), np.float32)])
+                qj, fj = jnp.asarray(q), jnp.asarray(f)
+                scores, ids, covered = self._dispatch_batch(
+                    qj, fj, k, n_real=len(idxs), alive=alive)
+                # the host copy waits for the last stage the batch ran
+                with span("fcvi.fetch"):
+                    scores, ids = np.asarray(scores), np.asarray(ids)
+                for j, i in enumerate(idxs):
+                    out_scores[i], out_ids[i] = scores[j], ids[j]
+                    if covered is not None:
+                        coverage[i] = covered[j]
+                    if use_cache:
+                        self._cache_put(keys[i], (scores[j], ids[j]))
 
         self.stats.queries += n
         self.stats.uncovered_queries += int((~coverage).sum())
         self.stats.last_coverage = coverage
-        self.stats.total_time_s += time.perf_counter() - t0
         return out_scores, out_ids
 
     # -- predicate-filtered search (filter algebra + planner) --------------
-    def _search_filtered(self, queries, pred: Predicate,
+    def _search_filtered(self, queries, pred: Predicate, sp,
                          plan: Optional[str] = None):
-        """Exact predicate-filtered top-k (see ``search`` docstring).
+        """Exact predicate-filtered top-k (see ``search`` docstring); ``sp``
+        is the call's ``fcvi.search`` span.
 
         The predicate compiles once per call to fixed-shape arrays
         (``repro.core.filters.compile_predicate``); eligibility is evaluated
@@ -744,7 +717,6 @@ class FCVIEngine:
             raise ValueError(
                 "predicate-filtered search needs a flat or ivf backend "
                 f"(index backend is {self.index.config.backend!r})")
-        t0 = time.perf_counter()
         q = np.asarray(queries, np.float32)
         if q.ndim != 2 or q.shape[0] == 0:
             raise ValueError(
@@ -771,6 +743,7 @@ class FCVIEngine:
             if plan == PLAN_ROUTED and not self.planner.routed_capable():
                 raise ValueError(
                     "plan='routed' needs an IVF backend or a sharded mesh")
+        sp.set_metadata(queries=n, plan=chosen)
         self.stats.queries += n
         self.stats.filtered_queries += n
         setattr(self.stats, f"plan_{chosen}",
@@ -790,7 +763,6 @@ class FCVIEngine:
         if n_elig + nd_elig == 0:
             # zero-match predicate: certified-empty results, not padded
             # id-0 garbage (coverage stays 1.0 — the answer IS empty)
-            self.stats.total_time_s += time.perf_counter() - t0
             return out_scores, out_ids
 
         # every plan scores against the SAME folded queries, computed once:
@@ -834,9 +806,6 @@ class FCVIEngine:
             scores, ids = flat_mod.finalize_filtered(d2, ids)
             out_scores[idxs] = np.asarray(scores)[: len(idxs)]
             out_ids[idxs] = np.asarray(ids, np.int64)[: len(idxs)]
-            self.stats.scan_batches += 1
-
-        self.stats.total_time_s += time.perf_counter() - t0
         return out_scores, out_ids
 
     def _filtered_main(self, plan: str, cp, q_t, elig_j, uniq, n_live, *,
@@ -868,10 +837,7 @@ class FCVIEngine:
                 # a pow-2 sub-batch (same pattern as _dense_subbatch)
                 fidx = np.nonzero(need)[0]
                 self.stats.filtered_fallbacks += len(fidx)
-                nb = b
-                while nb // 2 >= max(len(fidx), 1):
-                    nb //= 2
-                sel = np.zeros((nb,), np.int64)
+                sel = np.zeros((_subbatch_rows(len(fidx), b),), np.int64)
                 sel[: len(fidx)] = fidx
                 kpf = min(k + CANDIDATE_PAD, self.index.size)
                 d2f, idsf = _filtered_mask_step(
@@ -959,59 +925,65 @@ class FCVIEngine:
             dvn, dfn, dflat = delta.vn, delta.fn, delta.flat
         nr = q.shape[0] if n_real is None else n_real
         unc = None
-        if self._routed:
-            out = self._sharded.step(
-                self._sharded_delta_view(dflat), q, f,
-                k=k, kp=kp, kd=kd, routed=True, alive=alive,
-                gather_free=self.cfg.gather_free)
-            if degraded:
-                scores, ids, margin, flag, rmask, unc = out
-                unc = np.array(unc)
+        # stage 1 ends when its margins reach the host: stage 2 is decided
+        # there, so the chip idles from then until stage 2 is dispatched
+        with span("fcvi.step", kp=kp):
+            if self._routed:
+                out = self._sharded.step(
+                    self._sharded_delta_view(dflat), q, f,
+                    k=k, kp=kp, kd=kd, routed=True, alive=alive,
+                    gather_free=self.cfg.gather_free)
+                if degraded:
+                    scores, ids, margin, flag, rmask, unc = out
+                    unc = np.array(unc)
+                else:
+                    scores, ids, margin, flag, rmask = out
+                rm = np.asarray(rmask)
+                self.stats.routed_batches += 1
+                self.stats.shard_steps += rm.shape[1]
+                self.stats.shards_active += int(rm.any(axis=0).sum())
+                need = np.asarray(flag)[:nr]
+                if need.any():
+                    idxs = np.nonzero(need)[0]
+                    self.stats.router_fallbacks += len(idxs)
+                    sub = self._dense_subbatch(dvn, dfn, dflat, q, f, idxs,
+                                               k=k, kp=kp, kd=kd,
+                                               alive=alive)
+                    s2, i2, m2 = sub[:3]
+                    take = jnp.asarray(idxs)
+                    scores = scores.at[take].set(s2)
+                    ids = ids.at[take].set(i2)
+                    margin = margin.at[take].set(m2)
+                    if degraded:
+                        # the dense re-run's certificate (vs the dense k'-th
+                        # candidate) supersedes the routed one for these rows
+                        unc[idxs] = np.asarray(sub[3])
             else:
-                scores, ids, margin, flag, rmask = out
-            rm = np.asarray(rmask)
-            self.stats.routed_batches += 1
-            self.stats.shard_steps += rm.shape[1]
-            self.stats.shards_active += int(rm.any(axis=0).sum())
-            need = np.asarray(flag)[:nr]
-            if need.any():
-                idxs = np.nonzero(need)[0]
-                self.stats.router_fallbacks += len(idxs)
+                out = self._step(dvn, dfn, dflat, q, f, k=k, kp=kp, kd=kd,
+                                 alive=alive)
+                if degraded:
+                    scores, ids, margin, unc = out
+                    unc = np.array(unc)
+                else:
+                    scores, ids, margin = out
+            need = np.asarray(margin < self.cfg.escalate_margin)[:nr]
+        if need.any():
+            idxs = np.nonzero(need)[0]
+            kp2 = theory.k_prime(k, cfg.lam, alpha, self.index.size,
+                                 cfg.c * self.cfg.kprime_escalation)
+            rows = _subbatch_rows(len(idxs), q.shape[0])
+            self.stats.escalations += len(idxs)
+            self.stats.escalation_rows += rows
+            with span("fcvi.escalate", escalated=len(idxs), bucket=rows,
+                      kp=kp2):
                 sub = self._dense_subbatch(dvn, dfn, dflat, q, f, idxs,
-                                           k=k, kp=kp, kd=kd, alive=alive)
-                s2, i2, m2 = sub[:3]
+                                           k=k, kp=kp2, kd=kd, alive=alive)
+                s2, i2 = sub[:2]
                 take = jnp.asarray(idxs)
                 scores = scores.at[take].set(s2)
                 ids = ids.at[take].set(i2)
-                margin = margin.at[take].set(m2)
                 if degraded:
-                    # the dense re-run's certificate (vs the dense k'-th
-                    # candidate) supersedes the routed one for these rows
                     unc[idxs] = np.asarray(sub[3])
-        else:
-            out = self._step(dvn, dfn, dflat, q, f, k=k, kp=kp, kd=kd,
-                             alive=alive)
-            if degraded:
-                scores, ids, margin, unc = out
-                unc = np.array(unc)
-            else:
-                scores, ids, margin = out
-        need = np.asarray(margin < self.cfg.escalate_margin)
-        if n_real is not None:
-            need = need[:n_real]
-        if need.any():
-            idxs = np.nonzero(need)[0]
-            self.stats.escalations += len(idxs)
-            kp2 = theory.k_prime(k, cfg.lam, alpha, self.index.size,
-                                 cfg.c * self.cfg.kprime_escalation)
-            sub = self._dense_subbatch(dvn, dfn, dflat, q, f, idxs,
-                                       k=k, kp=kp2, kd=kd, alive=alive)
-            s2, i2 = sub[:2]
-            take = jnp.asarray(idxs)
-            scores = scores.at[take].set(s2)
-            ids = ids.at[take].set(i2)
-            if degraded:
-                unc[idxs] = np.asarray(sub[3])
         covered = None if unc is None else ~unc[:nr]
         return scores, ids, covered
 
@@ -1021,10 +993,7 @@ class FCVIEngine:
         dense step in a padded power-of-two sub-batch; pad slots recompute
         query 0. Returns the step's output rows for ``idxs`` (3 outputs, 4
         with a degraded ``alive`` mask)."""
-        nb = q.shape[0]
-        while nb // 2 >= max(len(idxs), 1):
-            nb //= 2
-        sel = np.zeros((nb,), np.int64)
+        sel = np.zeros((_subbatch_rows(len(idxs), q.shape[0]),), np.int64)
         sel[: len(idxs)] = idxs
         sel_j = jnp.asarray(sel)
         out = self._step(dvn, dfn, dflat, q[sel_j], f[sel_j],
