@@ -10,7 +10,12 @@ d = 768, m = 8 filter columns) is indexed and served through
 
   (a) flat, Pallas kernels;  (b) flat, pure XLA;
   (c) IVF (nlist 1024, nprobe 32), Pallas kernels;
-  (d) one predicate batch, ``filter=F.range(...) & F.isin(...)``, on (a).
+  (d) one predicate batch, ``filter=F.range(...) & F.isin(...)``, on (a);
+  (e) the IVF dedup kernel's selection steps on one batch of the benchmark
+      cell ``sift1m-ivf.bulk``: the corpus, index settings and query noise
+      of ``bench/configs/sift1m-ivf.json`` and ``bench/traffic/bulk512.json``,
+      made by the benchmark's own generator, at the engine's two k' (133
+      and the escalation's 533).
 
 (a)-(c) must reach recall@10 >= 0.95 against an fp64 NumPy brute force of
 the paper's combined score; (d) must return the exact filtered top-k by L2
@@ -41,6 +46,9 @@ K = 10
 BATCH = 64
 RECALL_BAR = 0.95           # the README quickstart's bar
 LAM, C = 0.6, 8.0           # k' = c*k/lam = 133 (Alg. 1 line 7)
+KP_STAGES = (133, 533)      # k' of stage 1 and of the escalation (c x 4)
+CELL_CONFIG = os.path.join(HERE, "bench", "configs", "sift1m-ivf.json")
+CELL_TRAFFIC = os.path.join(HERE, "bench", "traffic", "bulk512.json")
 
 
 def log(*parts):
@@ -187,6 +195,58 @@ def check_recall(label, ids, truth):
     return r
 
 
+def dedup_step_share(index, q, fq, kps=KP_STAGES):
+    """Selection steps the IVF dedup kernel takes on one batch, as the
+    engine's step would scan it: {k': (steps, share)}, the share of one
+    step per (slot, page) grid cell per k' that was taken."""
+    import jax.numpy as jnp
+
+    from repro.kernels import ops
+    from repro.kernels.ivf_score import page_rows
+
+    be = index.backend
+    qn, fqn = index.transform.normalize(jnp.asarray(q), jnp.asarray(fq))
+    q_t = index.transform.apply_normalized(qn, fqn)
+    c2 = jnp.sum(be.centroids * be.centroids, axis=-1)
+    _, probe = ops.score_topk_padded(be.centroids, c2, q_t,
+                                     index.config.nprobe)
+    uniq, member = ops.dedup_probes(probe.astype(jnp.int32), be.nlist)
+    row_bytes = be.grouped.shape[-1] * be.grouped.dtype.itemsize
+    cells = uniq.shape[0] * (be.max_list // page_rows(be.max_list,
+                                                      row_bytes))
+    out = {}
+    for kp in kps:
+        *_, steps = ops.ivf_score_topk_dedup(
+            be.grouped, be.grouped_sq, be.valid, uniq, member, q_t, kp,
+            scales=be.grouped_scales, count_steps=True)
+        out[kp] = (int(steps), int(steps) / (cells * kp))
+    return out
+
+
+def cell_step_share(cfg, traffic, seed):
+    """Phase (e): the benchmark's corpus of configuration ``cfg`` (drawn
+    from its ``corpus_seed``), indexed with its settings, and one batch of
+    ``traffic``'s queries drawn from ``seed``; logs the dedup kernel's
+    selection steps at each k' of ``KP_STAGES``."""
+    sys.path.insert(0, os.path.join(HERE, "bench"))
+    from harness import data, system
+
+    from repro.core import build
+
+    vectors, filters = data.corpus(cfg, cfg["corpus_seed"])
+    q, fq = data.queries(cfg, vectors, filters, seed, 1, BATCH,
+                         traffic["query_noise"])
+    t0 = time.perf_counter()
+    index = build(vectors, np.asarray(filters), system.fcvi_config(cfg))
+    index.vectors_n.block_until_ready()
+    log(f"[setup] (e) {cfg['name']} ivf index over {index.size} rows built "
+        f"in {time.perf_counter() - t0:.1f} s")
+    for kp, (steps, share) in dedup_step_share(index, q, fq).items():
+        log(f"(e) dedup selection at k'={kp}: {steps} steps, "
+            f"{100 * share:.2f}% of one per grid cell per k' "
+            f"(max list {index.backend.max_list})")
+
+
 # ---------------------------------------------------------------------------
 # one chip
 # ---------------------------------------------------------------------------
@@ -257,6 +317,15 @@ def run_one_chip(args, dev, corpus, q, fq):
     _, ids_i = serve(eng, q, fq, "(c) ivf pallas")
     check_recall("(c) ivf pallas", ids_i, truth)
     log(f"peak device bytes after IVF serving: {peak_bytes(dev)}")
+    del eng, index
+    gc.collect()
+
+    # (e) the dedup kernel's selection steps on the benchmark cell's data
+    with open(CELL_CONFIG) as fh:
+        cell_cfg = json.load(fh)
+    with open(CELL_TRAFFIC) as fh:
+        cell_traffic = json.load(fh)
+    cell_step_share(cell_cfg, cell_traffic, args.seed)
 
 
 # ---------------------------------------------------------------------------

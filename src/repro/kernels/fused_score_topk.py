@@ -7,11 +7,14 @@ the sequential corpus-block grid dimension. The corpus is therefore streamed
 through VMEM exactly once, and no (q x n) score matrix ever exists in HBM —
 the k-selection is fused into the scan.
 
-Top-k selection is a k-step max/mask sweep (max + iota-argmin) in a
-``fori_loop`` instead of lax.top_k, so every op lowers to plain TPU vector
-reductions and the kernel's code size does not grow with k. The running
-top-k and the block's scores stay two separate operands of the sweep (no
-lane concatenation at an unaligned offset).
+Top-k selection is a threshold-gated merge instead of lax.top_k: the
+running top-k stays sorted in a lane-aligned VMEM scratch, and each block
+gives up its best remaining score per row only while some row's best beats
+that row's running k-th value. The loop's trip count follows the data: a
+block no query probed, or one whose scores all fall below every row's k-th
+value, costs one pass over it, and no block costs more than k steps. Every
+op lowers to plain TPU vector reductions and a one-lane rotate, and the
+kernel's code size does not grow with k.
 
 TPU layout: per-row operands (squared norms, int8 scales, the eligibility
 mask) are carried lane-major as (1, n) rows and the per-query squared norms
@@ -45,51 +48,77 @@ def compiler_params(vmem_bytes: int, semantics):
                                 vmem_limit_bytes=int(limit))
 
 
-def merge_topk(run_v, run_i, blk_v, blk_i, k: int):
-    """First-occurrence top-k of the concatenation [running | block] along
-    the last axis, without materialising the concatenation.
+def lane_width(k: int) -> int:
+    """Lanes of the running top-k scratch: k rounded up to whole vregs."""
+    return -(-k // 128) * 128
 
-    run_v/run_i: (q, kr); blk_v/blk_i: (q, c). Returns (vals, ids), each
-    (q, k), descending by value. Ties go to the lowest position, exactly
-    like ``lax.top_k`` over the concatenation.
+
+def init_running(run_v_ref, run_i_ref):
+    """Empty running top-k: every slot -inf with id 0 (the fill a row with
+    fewer than k finite scores ends with)."""
+    run_v_ref[...] = jnp.full_like(run_v_ref, NEG_INF)
+    run_i_ref[...] = jnp.zeros_like(run_i_ref)
+
+
+def merge_topk(run_v_ref, run_i_ref, blk_v, base, k: int):
+    """Merge one block of scores into the sorted running top-k held in
+    ``run_v_ref``/``run_i_ref``, taking from the block only the scores that
+    enter; returns the selection steps taken, 0 to k.
+
+    run_v_ref/run_i_ref: (q, lane_width(k)) VMEM; lanes [0, k) hold the
+    running top-k in descending order (ties in order of occurrence), lanes
+    past k hold scores that no longer count (none above the k-th). blk_v:
+    (q, c) block scores; the id of column j is ``base + j``.
+
+    Each step takes every row's best remaining block score (first column
+    on ties) and inserts it where it beats the row's running k-th value,
+    after the running entries it ties, by a one-lane shift. A row whose
+    best no longer beats its k-th value never will again within the block
+    (the k-th value only rises, the block's best only falls), so the loop
+    stops when no row's does. The result equals a first-occurrence
+    ``lax.top_k`` over everything merged so far, as a k-step max/mask sweep
+    over [running | block] gives it.
     """
-    q, kr = run_v.shape
+    q, kw = run_v_ref.shape
     c = blk_v.shape[-1]
-    big = kr + c
-    iota_r = jax.lax.broadcasted_iota(jnp.int32, (q, kr), 1)
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (q, c), 1) + kr
-    slot = jax.lax.broadcasted_iota(jnp.int32, (q, k), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (q, kw), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (q, c), 1)
 
-    def body(t, carry):
-        cur_r, cur_b, out_v, out_i = carry
-        m = jnp.maximum(jnp.max(cur_r, axis=-1, keepdims=True),
-                        jnp.max(cur_b, axis=-1, keepdims=True))
-        pos = jnp.minimum(
-            jnp.min(jnp.where(cur_r == m, iota_r, big), axis=-1,
-                    keepdims=True),
-            jnp.min(jnp.where(cur_b == m, iota_b, big), axis=-1,
-                    keepdims=True))
-        sel_r = iota_r == pos
-        sel_b = iota_b == pos
-        ident = (jnp.sum(jnp.where(sel_r, run_i, 0), axis=-1, keepdims=True)
-                 + jnp.sum(jnp.where(sel_b, blk_i, 0), axis=-1,
-                           keepdims=True))
-        here = slot == t
-        return (jnp.where(sel_r, NEG_INF, cur_r),
-                jnp.where(sel_b, NEG_INF, cur_b),
-                jnp.where(here, m, out_v),
-                jnp.where(here, ident, out_i))
+    def gate(rv, blk):
+        """(best remaining block score, the slot it would take) per row; a
+        slot of k or more means it does not enter."""
+        m = jnp.max(blk, axis=-1, keepdims=True)
+        return m, jnp.sum((rv >= m).astype(jnp.int32), axis=-1,
+                          keepdims=True)
 
-    init = (run_v, blk_v, jnp.full((q, k), NEG_INF, jnp.float32),
-            jnp.zeros((q, k), jnp.int32))
-    _, _, vals, ids = jax.lax.fori_loop(0, k, body, init)
-    return vals, ids
+    def cond(carry):
+        t, _, _, _, _, slot = carry
+        live = jnp.max((slot < k).astype(jnp.int32))
+        return (t < k) & (live > 0)
+
+    def body(carry):
+        t, rv, ri, blk, m, slot = carry
+        first = jnp.min(jnp.where(blk == m, col, c), axis=-1, keepdims=True)
+        after = lane > slot
+        rv = jnp.where(after, pltpu.roll(rv, 1, 1),
+                       jnp.where(lane == slot, m, rv))
+        ri = jnp.where(after, pltpu.roll(ri, 1, 1),
+                       jnp.where(lane == slot, base + first, ri))
+        blk = jnp.where(col == first, NEG_INF, blk)
+        return (t + 1, rv, ri, blk) + gate(rv, blk)
+
+    run_v = run_v_ref[...]
+    init = (jnp.int32(0), run_v, run_i_ref[...], blk_v) + gate(run_v, blk_v)
+    steps, run_v, run_i, _, _, _ = jax.lax.while_loop(cond, body, init)
+    run_v_ref[...] = run_v
+    run_i_ref[...] = run_i
+    return steps
 
 
-def _block_scores(j, x_ref, xsq_ref, scale_ref, mask_ref, q_ref, qsq_ref,
-                  block_rows: int):
-    """(scores (bq, bn), global row ids (bq, bn)) of one grid cell. ``scale_ref``/``mask_ref`` are None for the plain variants.
-    The int8 scale multiplies the matmul OUTPUT column (fp32 accumulation)."""
+def _block_scores(x_ref, xsq_ref, scale_ref, mask_ref, q_ref, qsq_ref):
+    """Scores (bq, bn) of one grid cell. ``scale_ref``/``mask_ref`` are None
+    for the plain variants. The int8 scale multiplies the matmul OUTPUT
+    column (fp32 accumulation)."""
     x = x_ref[...].astype(jnp.float32)                 # (bn, d)
     q = q_ref[...]                                      # (bq, d)
     scores = 2.0 * jnp.dot(q, x.T, preferred_element_type=jnp.float32)
@@ -98,9 +127,7 @@ def _block_scores(j, x_ref, xsq_ref, scale_ref, mask_ref, q_ref, qsq_ref,
     scores = scores - xsq_ref[...] - qsq_ref[...]       # (1, bn), (bq, 1)
     if mask_ref is not None:
         scores = jnp.where(mask_ref[...] > 0.5, scores, NEG_INF)
-    gids = j * block_rows + jax.lax.broadcasted_iota(jnp.int32, scores.shape,
-                                                     1)
-    return scores, gids
+    return scores
 
 
 def _scan_kernel(*refs, k: int, block_rows: int, has_scale: bool,
@@ -112,19 +139,21 @@ def _scan_kernel(*refs, k: int, block_rows: int, has_scale: bool,
     x_ref, xsq_ref = refs.pop(0), refs.pop(0)
     scale_ref = refs.pop(0) if has_scale else None
     mask_ref = refs.pop(0) if has_mask else None
-    q_ref, qsq_ref, vals_ref, idx_ref = refs
+    q_ref, qsq_ref, vals_ref, idx_ref, run_v_ref, run_i_ref = refs
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
-        idx_ref[...] = jnp.zeros_like(idx_ref)
+        init_running(run_v_ref, run_i_ref)
 
-    scores, gids = _block_scores(j, x_ref, xsq_ref, scale_ref, mask_ref,
-                                 q_ref, qsq_ref, block_rows)
-    new_v, new_i = merge_topk(vals_ref[...], idx_ref[...], scores, gids, k)
-    vals_ref[...] = new_v
-    idx_ref[...] = new_i
+    scores = _block_scores(x_ref, xsq_ref, scale_ref, mask_ref, q_ref,
+                           qsq_ref)
+    merge_topk(run_v_ref, run_i_ref, scores, j * block_rows, k)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _emit():
+        vals_ref[...] = run_v_ref[:, :k]
+        idx_ref[...] = run_i_ref[:, :k]
 
 
 def _check_tiling(n, nq, k, block_rows, block_q):
@@ -177,7 +206,8 @@ def score_topk(corpus, sq_norms, queries, k: int, *, scales=None, mask=None,
     kernel = functools.partial(_scan_kernel, k=k, block_rows=block_rows,
                                has_scale=scales is not None,
                                has_mask=mask is not None)
-    tile = block_q * (k + block_rows) * 4
+    kw = lane_width(k)
+    tile = block_q * (kw + block_rows) * 4
     vmem = (2 * block_rows * d * corpus.dtype.itemsize
             + 2 * block_q * d * 4 + 12 * tile)
     return pl.pallas_call(
@@ -185,6 +215,8 @@ def score_topk(corpus, sq_norms, queries, k: int, *, scales=None, mask=None,
         out_specs=(out_spec, out_spec),
         out_shape=(jax.ShapeDtypeStruct((nq, k), jnp.float32),
                    jax.ShapeDtypeStruct((nq, k), jnp.int32)),
+        scratch_shapes=[pltpu.VMEM((block_q, kw), jnp.float32),
+                        pltpu.VMEM((block_q, kw), jnp.int32)],
         compiler_params=compiler_params(vmem, ("parallel", "arbitrary")),
         interpret=interpret,
     )(*args)

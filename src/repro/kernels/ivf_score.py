@@ -37,6 +37,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.fused_score_topk import (NEG_INF, compiler_params,
+                                            init_running, lane_width,
                                             merge_topk)
 
 # slab bytes one grid step may DMA before a list is paged
@@ -75,14 +76,13 @@ def _batch_kernel(*refs, k: int, max_list: int, has_scale: bool):
     probes_ref, slab_ref, sq_ref = refs[:3]
     refs = refs[3:]
     sc_ref = refs.pop(0) if has_scale else None
-    valid_ref, q_ref, vals_ref, idx_ref = refs
+    valid_ref, q_ref, vals_ref, idx_ref, run_v_ref, run_i_ref = refs
     i = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
-        idx_ref[...] = jnp.zeros_like(idx_ref)
+        init_running(run_v_ref, run_i_ref)
 
     slab = slab_ref[0].astype(jnp.float32)               # (max_list, d)
     q = q_ref[0]                                         # (1, d)
@@ -91,12 +91,12 @@ def _batch_kernel(*refs, k: int, max_list: int, has_scale: bool):
         s = s * sc_ref[0]
     s = s - sq_ref[0]                                    # (1, max_list)
     s = jnp.where(valid_ref[0] > 0.5, s, NEG_INF)
-    list_id = probes_ref[i, j]
-    gids = (list_id * max_list
-            + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-    new_v, new_i = merge_topk(vals_ref[0], idx_ref[0], s, gids, k)
-    vals_ref[0] = new_v
-    idx_ref[0] = new_i
+    merge_topk(run_v_ref, run_i_ref, s, probes_ref[i, j] * max_list, k)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _emit():
+        vals_ref[0] = run_v_ref[:, :k]
+        idx_ref[0] = run_i_ref[:, :k]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -130,12 +130,15 @@ def ivf_score_topk_batch(grouped, grouped_sq, valid, probes, queries, k: int,
     args += [_rows3(valid), queries.reshape(b, 1, d)]
     kernel = functools.partial(_batch_kernel, k=k, max_list=max_list,
                                has_scale=scales is not None)
-    vmem = 2 * max_list * d * grouped.dtype.itemsize + 16 * (k + max_list) * 4
+    kw = lane_width(k)
+    vmem = 2 * max_list * d * grouped.dtype.itemsize + 16 * (kw + max_list) * 4
     vals, idx = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(b, nprobe), in_specs=in_specs,
-            out_specs=(per_query(k), per_query(k))),
+            out_specs=(per_query(k), per_query(k)),
+            scratch_shapes=[pltpu.VMEM((1, kw), jnp.float32),
+                            pltpu.VMEM((1, kw), jnp.int32)]),
         out_shape=(jax.ShapeDtypeStruct((b, 1, k), jnp.float32),
                    jax.ShapeDtypeStruct((b, 1, k), jnp.int32)),
         compiler_params=compiler_params(vmem, ("parallel", "arbitrary")),
@@ -144,21 +147,26 @@ def ivf_score_topk_batch(grouped, grouped_sq, valid, probes, queries, k: int,
     return vals[:, 0], idx[:, 0]
 
 
-def _dedup_kernel(*refs, k: int, max_list: int, page: int, has_scale: bool):
+def _dedup_kernel(*refs, k: int, max_list: int, page: int, has_scale: bool,
+                  count_steps: bool):
     """Probe-major scan of one (unique list, page) grid cell for the whole
-    query batch; queries that did not probe the list score -inf."""
+    query batch; queries that did not probe the list score -inf, so a slot
+    no query probed takes no selection step."""
     refs = list(refs)
     uniq_ref, slab_ref, sq_ref = refs[:3]
     refs = refs[3:]
     sc_ref = refs.pop(0) if has_scale else None
-    valid_ref, member_ref, q_ref, vals_ref, idx_ref = refs
+    valid_ref, member_ref, q_ref, vals_ref, idx_ref = refs[:5]
+    steps_ref = refs[5] if count_steps else None
+    run_v_ref, run_i_ref = refs[-2:]
     s = pl.program_id(0)
     p = pl.program_id(1)
 
     @pl.when((s == 0) & (p == 0))
     def _init():
-        vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
-        idx_ref[...] = jnp.zeros_like(idx_ref)
+        init_running(run_v_ref, run_i_ref)
+        if steps_ref is not None:
+            steps_ref[0, 0] = 0
 
     slab = slab_ref[0].astype(jnp.float32)              # (page, d)
     q = q_ref[...]                                      # (b, d)
@@ -169,17 +177,21 @@ def _dedup_kernel(*refs, k: int, max_list: int, page: int, has_scale: bool):
     mem = _row_to_col(member_ref[0])                     # (b, 1)
     keep = (valid_ref[0] > 0.5) & (mem > 0.5)
     scores = jnp.where(keep, scores, NEG_INF)
-    gids = (uniq_ref[s] * max_list + p * page
-            + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1))
-    new_v, new_i = merge_topk(vals_ref[...], idx_ref[...], scores, gids, k)
-    vals_ref[...] = new_v
-    idx_ref[...] = new_i
+    steps = merge_topk(run_v_ref, run_i_ref, scores,
+                       uniq_ref[s] * max_list + p * page, k)
+    if steps_ref is not None:
+        steps_ref[0, 0] += steps
+
+    @pl.when((s == pl.num_programs(0) - 1) & (p == pl.num_programs(1) - 1))
+    def _emit():
+        vals_ref[...] = run_v_ref[:, :k]
+        idx_ref[...] = run_i_ref[:, :k]
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("k", "count_steps", "interpret"))
 def ivf_score_topk_dedup(grouped, grouped_sq, valid, uniq, member, queries,
                          k: int, *, scales=None, mask=None,
-                         interpret: bool):
+                         count_steps: bool = False, interpret: bool):
     """Probe-major batched slab search over the deduplicated probed lists.
 
     grouped: (nlist, max_list, d); grouped_sq/valid: (nlist, max_list);
@@ -195,6 +207,8 @@ def ivf_score_topk_dedup(grouped, grouped_sq, valid, uniq, member, queries,
     (nlist, max_list) float 0/1 is the filter algebra's candidate mask: it
     multiplies into the validity operand the kernel streams, so ineligible
     rows score -inf inside the scan (exact — both operands are 0/1).
+    ``count_steps`` also returns the selection steps the call took, an
+    int32 scalar: at most k per (slot, page) grid cell.
     """
     if mask is not None:
         valid = valid * mask
@@ -202,6 +216,7 @@ def ivf_score_topk_dedup(grouped, grouped_sq, valid, uniq, member, queries,
     b = queries.shape[0]
     slots = uniq.shape[0]
     page = page_rows(max_list, d * grouped.dtype.itemsize)
+    kw = lane_width(k)
 
     slab_spec = pl.BlockSpec((1, page, d), lambda s, p, uniq: (uniq[s], p, 0))
     row_spec = pl.BlockSpec((1, 1, page), lambda s, p, uniq: (uniq[s], 0, p))
@@ -215,20 +230,31 @@ def ivf_score_topk_dedup(grouped, grouped_sq, valid, uniq, member, queries,
                  pl.BlockSpec((b, d), lambda s, p, uniq: (0, 0))]
     args += [_rows3(valid), _rows3(member), queries]
     out_spec = pl.BlockSpec((b, k), lambda s, p, uniq: (0, 0))
+    out_specs = [out_spec, out_spec]
+    out_shape = [jax.ShapeDtypeStruct((b, k), jnp.float32),
+                 jax.ShapeDtypeStruct((b, k), jnp.int32)]
+    if count_steps:
+        out_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        out_shape.append(jax.ShapeDtypeStruct((1, 1), jnp.int32))
     kernel = functools.partial(_dedup_kernel, k=k, max_list=max_list,
-                               page=page, has_scale=scales is not None)
+                               page=page, has_scale=scales is not None,
+                               count_steps=count_steps)
     vmem = (2 * page * d * grouped.dtype.itemsize + 2 * b * d * 4
-            + 12 * b * (k + page) * 4)
-    return pl.pallas_call(
+            + 12 * b * (kw + page) * 4)
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(slots, max_list // page),
-            in_specs=in_specs, out_specs=(out_spec, out_spec)),
-        out_shape=(jax.ShapeDtypeStruct((b, k), jnp.float32),
-                   jax.ShapeDtypeStruct((b, k), jnp.int32)),
+            in_specs=in_specs, out_specs=tuple(out_specs),
+            scratch_shapes=[pltpu.VMEM((b, kw), jnp.float32),
+                            pltpu.VMEM((b, kw), jnp.int32)]),
+        out_shape=tuple(out_shape),
         compiler_params=compiler_params(vmem, ("arbitrary", "arbitrary")),
         interpret=interpret,
     )(*args)
+    if count_steps:
+        return out[0], out[1], out[2][0, 0]
+    return out
 
 
 def dedup_probes(probes, nlist: int):

@@ -171,19 +171,26 @@ def ivf_score_topk_batch(grouped, grouped_sq, valid, probes, queries, k, *,
 
 
 def ivf_score_topk_dedup(grouped, grouped_sq, valid, uniq, member, queries, k,
-                         *, scales=None, mask=None, use_pallas: bool = True):
+                         *, scales=None, mask=None, count_steps: bool = False,
+                         use_pallas: bool = True):
     """Probe-major deduplicated batched slab search: uniq (s,), member (s, b),
     queries (b, d). Shared lists are DMA'd once per batch (see
     ``ivf_score.dedup_probes`` for building uniq/member from a probe matrix).
     ``mask`` (nlist, max_list) float 0/1 is the filter algebra's candidate
     mask, folded into the validity operand the kernel streams.
+    ``count_steps`` also returns the kernel's selection steps (an int32
+    scalar); the oracle takes none, so it needs ``use_pallas``.
     """
     if not use_pallas:
+        if count_steps:
+            raise ValueError("count_steps counts the Pallas kernel's "
+                             "selection steps; it needs use_pallas=True")
         return ref.ref_ivf_score_topk_dedup(grouped, grouped_sq, valid > 0.5,
                                             uniq, member > 0.5, queries, k,
                                             scales=scales, mask=mask)
     return _ivf_score_topk_dedup(grouped, grouped_sq, valid, uniq, member,
                                  queries, k, scales=scales, mask=mask,
+                                 count_steps=count_steps,
                                  interpret=_interpret())
 
 

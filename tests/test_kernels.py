@@ -30,18 +30,54 @@ def test_fused_transform(n, d, m, dtype):
                                rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("n,d,q,k", [(512, 64, 64, 8), (256, 128, 128, 16),
-                                     (1024, 32, 64, 32)])
-def test_score_topk(n, d, q, k):
+def _ints(r, shape, dtype, lo=-2, hi=2):
+    """Small-integer data: every score is exact in fp32 (and the rows exact
+    in bf16), so equal scores tie exactly and any order of summation agrees."""
+    return jnp.asarray(r.integers(lo, hi + 1, size=shape).astype(np.float32),
+                       dtype)
+
+
+@pytest.mark.parametrize("n,d,q,k,case", [
+    pytest.param(512, 64, 64, 8, "random", id="512-64-64-8"),
+    pytest.param(256, 128, 128, 16, "random", id="256-128-128-16"),
+    pytest.param(1024, 32, 64, 32, "random", id="1024-32-64-32"),
+    # exact ties within a block and across blocks (every row four times)
+    pytest.param(512, 16, 64, 24, "ties", id="ties"),
+    # 20 eligible rows for k = 40: -inf fill with id 0
+    pytest.param(256, 32, 64, 40, "mask-short", id="mask-short"),
+    # k larger than one corpus block
+    pytest.param(512, 16, 64, 160, "ints", id="k-over-block"),
+])
+def test_score_topk(n, d, q, k, case):
     r = np.random.default_rng(n + k)
-    corpus = _rand(r, (n, d), jnp.float32)
-    queries = _rand(r, (q, d), jnp.float32)
+    if case == "random":
+        corpus = _rand(r, (n, d), jnp.float32)
+        queries = _rand(r, (q, d), jnp.float32)
+        sq = jnp.sum(corpus * corpus, -1)
+        v1, i1 = ops.score_topk(corpus, sq, queries, k, block_rows=128,
+                                block_q=64)
+        v2, i2 = ref.ref_score_topk(corpus, sq, queries, k)
+        np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), rtol=1e-4,
+                                   atol=1e-4)
+        assert (np.asarray(i1) == np.asarray(i2)).mean() > 0.999
+        return
+    corpus = _ints(r, (n, d), jnp.float32, -1, 1)
+    if case == "ties":
+        corpus = jnp.tile(corpus[:n // 4], (4, 1))
+    queries = _ints(r, (q, d), jnp.float32)
     sq = jnp.sum(corpus * corpus, -1)
-    v1, i1 = ops.score_topk(corpus, sq, queries, k, block_rows=128, block_q=64)
-    v2, i2 = ref.ref_score_topk(corpus, sq, queries, k)
-    np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), rtol=1e-4,
-                               atol=1e-4)
-    assert (np.asarray(i1) == np.asarray(i2)).mean() > 0.999
+    mask = None
+    if case == "mask-short":
+        keep = r.choice(n, 20, replace=False)
+        mask = jnp.zeros((n,), jnp.float32).at[keep].set(1.0)
+    v1, i1 = ops.score_topk(corpus, sq, queries, k, mask=mask,
+                            block_rows=128, block_q=64)
+    v2, i2 = ref.ref_score_topk(corpus, sq, queries, k, mask=mask)
+    np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+    if case == "mask-short":
+        assert np.isneginf(np.asarray(v1)[:, 20:]).all()
+        assert (np.asarray(i1)[:, 20:] == 0).all()
 
 
 @pytest.mark.parametrize("b,kp,d,m", [(8, 32, 64, 4), (16, 64, 128, 8)])
@@ -95,15 +131,79 @@ def test_ivf_score_topk_batch(b, nlist, maxl, d, nprobe, k):
     assert (np.asarray(i1) == np.asarray(i2)).all()
 
 
-@pytest.mark.parametrize("b,nlist,maxl,d,nprobe,k",
-                         [(4, 8, 64, 64, 3, 8), (6, 16, 128, 32, 5, 16)])
+def _dedup_fixture(case, r, b, nlist, maxl, d, nprobe, dtype):
+    """(grouped, gsq, valid, probes, queries, mask) of a small-integer case
+    (see ``_ints``); each query's probes in ascending list order, the order
+    the dedup kernel walks, so the per-probe kernel meets ties in the same
+    order."""
+    grouped = _ints(r, (nlist, maxl, d), jnp.float32, -1, 1)
+    if case == "ties":      # every list's rows again in another list
+        grouped = jnp.concatenate([grouped[:nlist // 2]] * 2)
+    grouped = grouped.astype(dtype)
+    gsq = jnp.sum(grouped.astype(jnp.float32) ** 2, -1)
+    valid = jnp.asarray((r.random((nlist, maxl)) > 0.15).astype(np.float32))
+    if case == "filler":    # one shared probe set: most slots are filler
+        probes = np.tile(r.choice(nlist, nprobe, replace=False), (b, 1))
+    elif case == "sparse":  # disjoint probe sets: one member per slot
+        probes = r.permutation(nlist)[:b * nprobe].reshape(b, nprobe)
+    else:
+        probes = np.stack([r.choice(nlist, nprobe, replace=False)
+                           for _ in range(b)])
+    probes = jnp.asarray(np.sort(probes, axis=1).astype(np.int32))
+    mask = None
+    if case == "mask":
+        mask = jnp.asarray((r.random((nlist, maxl)) > 0.5).astype(np.float32))
+    return grouped, gsq, valid, probes, _ints(r, (b, d), jnp.float32), mask
+
+
+@pytest.mark.parametrize("b,nlist,maxl,d,nprobe,k,case", [
+    pytest.param(4, 8, 64, 64, 3, 8, "random", id="4-8-64-64-3-8"),
+    pytest.param(6, 16, 128, 32, 5, 16, "random", id="6-16-128-32-5-16"),
+    # exact score ties within a list and across slots (duplicated rows)
+    pytest.param(6, 16, 64, 16, 5, 24, "ties", id="ties"),
+    # at most 2 x 64 valid rows for k = 160 (k over one page): -inf, id 0
+    pytest.param(5, 8, 64, 16, 2, 160, "short", id="short-rows"),
+    # 3 live slots of 12, the rest all-zero member filler
+    pytest.param(4, 32, 64, 16, 3, 16, "filler", id="filler"),
+    # each slot probed by one query of 8
+    pytest.param(8, 32, 64, 16, 2, 16, "sparse", id="member-sparse"),
+    # a list longer than PAGE_BUDGET, scanned in pages, k over one page
+    pytest.param(4, 4, 1536, 1024, 2, 800, "paged", id="paged"),
+    # the filter algebra's candidate mask
+    pytest.param(6, 16, 64, 16, 5, 24, "mask", id="mask"),
+])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_ivf_score_topk_dedup(b, nlist, maxl, d, nprobe, k, dtype):
+def test_ivf_score_topk_dedup(b, nlist, maxl, d, nprobe, k, case, dtype):
     """Probe-major dedup kernel vs its oracle AND the per-probe batch kernel:
     deduplicating shared slabs must not change any result."""
-    from repro.kernels.ivf_score import dedup_probes
+    from repro.kernels.ivf_score import dedup_probes, page_rows
 
     r = np.random.default_rng(b + nlist)
+    if case != "random":
+        grouped, gsq, valid, probes, qs, mask = _dedup_fixture(
+            case, r, b, nlist, maxl, d, nprobe, dtype)
+        uniq, member = dedup_probes(probes, nlist)
+        live = np.asarray(member).any(axis=1)
+        if case == "filler":
+            assert live.sum() == nprobe < live.size
+        if case == "sparse":
+            assert (np.asarray(member).sum(axis=1) == 1).all()
+        if case == "paged":
+            assert page_rows(maxl, d * grouped.dtype.itemsize) < k < maxl
+        v1, i1 = ops.ivf_score_topk_dedup(grouped, gsq, valid, uniq, member,
+                                          qs, k, mask=mask)
+        v2, i2 = ops.ivf_score_topk_dedup(grouped, gsq, valid, uniq, member,
+                                          qs, k, mask=mask, use_pallas=False)
+        vb, ib = ops.ivf_score_topk_batch(
+            grouped, gsq, valid if mask is None else valid * mask, probes,
+            qs, k)
+        for v, i in ((v2, i2), (vb, ib)):
+            np.testing.assert_array_equal(np.asarray(v1), np.asarray(v))
+            np.testing.assert_array_equal(np.asarray(i1), np.asarray(i))
+        if case == "short":
+            dead = np.isneginf(np.asarray(v1))
+            assert dead.any() and (np.asarray(i1)[dead] == 0).all()
+        return
     grouped = _rand(r, (nlist, maxl, d), dtype)
     gsq = jnp.sum(grouped.astype(jnp.float32) ** 2, -1)
     valid = jnp.asarray((r.random((nlist, maxl)) > 0.15).astype(np.float32))
@@ -123,6 +223,49 @@ def test_ivf_score_topk_dedup(b, nlist, maxl, d, nprobe, k, dtype):
     np.testing.assert_allclose(np.asarray(v1), np.asarray(vb),
                                rtol=1e-4, atol=1e-4)
     assert (np.asarray(i1) == np.asarray(ib)).all()
+
+
+def _gate_steps(blocks, k):
+    """The gated merge's step count in NumPy: per block, the most entries
+    any row takes from it into its running top-k (first occurrence wins a
+    tie, so a later equal score does not enter)."""
+    run = np.full((blocks[0].shape[0], k), -np.inf)
+    steps = 0
+    for blk in blocks:
+        both = np.concatenate([run, blk], axis=1)
+        top = np.argsort(-both, axis=1, kind="stable")[:, :k]
+        steps += int((top >= k).sum(axis=1).max())
+        run = np.take_along_axis(both, top, axis=1)
+    return steps
+
+
+@pytest.mark.parametrize("b,nlist,maxl,d,nprobe,k",
+                         [(6, 16, 64, 16, 5, 24), (8, 32, 128, 16, 3, 40)])
+def test_ivf_dedup_count_steps(b, nlist, maxl, d, nprobe, k):
+    """``count_steps`` returns the selection steps of a NumPy model of the
+    gate (at most k per slot) and leaves the results as they were."""
+    from repro.kernels.ivf_score import dedup_probes
+
+    r = np.random.default_rng(b * nlist)
+    grouped, gsq, valid, probes, qs, _ = _dedup_fixture(
+        "ints", r, b, nlist, maxl, d, nprobe, jnp.float32)
+    uniq, member = dedup_probes(probes, nlist)
+    args = (grouped, gsq, valid, uniq, member, qs, k)
+    v0, i0 = ops.ivf_score_topk_dedup(*args)
+    v1, i1, steps = ops.ivf_score_topk_dedup(*args, count_steps=True)
+    np.testing.assert_array_equal(np.asarray(v0), np.asarray(v1))
+    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
+
+    g, sq, ok = (np.asarray(a, np.float64) for a in (grouped, gsq, valid))
+    qn, mem = np.asarray(qs, np.float64), np.asarray(member)
+    blocks = [np.where((ok[u] > 0.5)[None, :] & (mem[s] > 0.5)[:, None],
+                       2.0 * qn @ g[u].T - sq[u][None, :], -np.inf)
+              for s, u in enumerate(np.asarray(uniq))]
+    want = _gate_steps(blocks, k)
+    assert int(steps) == want
+    assert 0 < want < len(blocks) * k
+    with pytest.raises(ValueError):
+        ops.ivf_score_topk_dedup(*args, count_steps=True, use_pallas=False)
 
 
 def test_score_topk_padded_arbitrary_shapes():

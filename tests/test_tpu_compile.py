@@ -117,15 +117,36 @@ def _ivf_index(grouped, gsq, valid, centroids, lists, vectors, sq):
         grouped_sq=gsq, valid=valid)
 
 
-def test_ivf_dedup(compile_v5e):
+# the benchmark's IVF deployment: SIFT1M widths and its longest list (one
+# page per list), at both k' of the escalating engine: stage 1 and stage 2
+# (c = 8 x kprime_escalation 4)
+CELL_D, CELL_MAX_LIST, KP2 = 128, 2592, 533
+# one shard of the IVF engine on a 2x2 mesh (serve/sharded.py): its 256
+# lists plus the all-invalid sentinel slot every non-local probe goes to
+SHARD_SLOTS = NLIST // 4 + 1
+
+
+@pytest.mark.parametrize("slots,d,max_list,kp,count_steps", [
+    pytest.param(NLIST, D, MAX_LIST, KP, False, id="deployment"),
+    pytest.param(NLIST, CELL_D, CELL_MAX_LIST, KP, False, id="cell-kp133"),
+    pytest.param(NLIST, CELL_D, CELL_MAX_LIST, KP2, False, id="cell-kp533"),
+    pytest.param(NLIST, CELL_D, CELL_MAX_LIST, KP, True,
+                 id="cell-kp133-steps"),
+    pytest.param(NLIST, CELL_D, CELL_MAX_LIST, KP2, True,
+                 id="cell-kp533-steps"),
+    pytest.param(SHARD_SLOTS, D, MAX_LIST, KP, False, id="shard-kp133"),
+    pytest.param(SHARD_SLOTS, D, MAX_LIST, KP2, False, id="shard-kp533"),
+])
+def test_ivf_dedup(compile_v5e, slots, d, max_list, kp, count_steps):
     def fn(grouped, gsq, valid, uniq, member, queries):
         return ivf_score.ivf_score_topk_dedup(grouped, gsq, valid, uniq,
-                                              member, queries, KP,
+                                              member, queries, kp,
+                                              count_steps=count_steps,
                                               interpret=False)
 
-    compile_v5e(fn, ((NLIST, MAX_LIST, D), F32), ((NLIST, MAX_LIST), F32),
-                ((NLIST, MAX_LIST), F32), ((NLIST,), I32), ((NLIST, B), F32),
-                ((B, D), F32))
+    compile_v5e(fn, ((slots, max_list, d), F32), ((slots, max_list), F32),
+                ((slots, max_list), F32), ((slots,), I32), ((slots, B), F32),
+                ((B, d), F32))
 
 
 def test_ivf_search_rerank(compile_v5e):
