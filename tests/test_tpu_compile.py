@@ -4,10 +4,11 @@ Interpret mode cannot see what the TPU compiler refuses: block shapes that
 do not tile as (8, 128), 1-D blocks whose layout differs from XLA's, or
 blocks and temporaries that overflow VMEM. Each test lowers one kernel (or
 the engine step's scan + re-rank around it) at a deployment width — n = 2^20
-rows, d = 768, batch 64, k' = 133 (+ the refine pad), m = 8 filter columns,
-IVF nlist = 1024 with 2048-row lists and nprobe = 32 — for one chip of a
-described ``v5e:2x2`` topology and checks that the Mosaic kernel is in the
-compiled program. Nothing runs and nothing needs a chip.
+rows, d = 768, batch 64, k' = 133 and the escalation's 533 (+ the refine
+pad), m = 8 filter columns, IVF nlist = 1024 with 2048-row lists and
+nprobe = 32 — for one chip of a described ``v5e:2x2`` topology and checks
+that the Mosaic kernel is in the compiled program. Nothing runs and nothing
+needs a chip.
 
 The topology is described inside a module fixture (never at import), and
 the persistent compilation cache is off around the compiles: a compile for
@@ -28,7 +29,9 @@ from repro.kernels import ops, pq_lut, rescore
 
 N, D, B, M = 1 << 20, 768, 64, 8
 KP = 133                       # k' of k=10, lam=0.6, c=8 (theory.k_prime)
-KK = KP + flat_mod.REFINE_PAD  # what the flat scan kernel is asked for
+# the escalation's k' (c = 8 x kprime_escalation 4): the engine's stage 2
+# re-runs a batch's ambiguous queries through the same scan at this k'
+KP2 = 533
 NLIST, MAX_LIST, NPROBE = 1024, 2048, 32
 
 
@@ -79,14 +82,23 @@ def compile_v5e(one_chip, no_compile_cache, monkeypatch):
 F32, I32 = jnp.float32, jnp.int32
 
 
-@pytest.mark.parametrize("variant", ["plain", "masked", "int8"])
-def test_flat_scan(compile_v5e, variant):
+@pytest.mark.parametrize("variant,kp", [
+    pytest.param("plain", KP, id="plain"),
+    pytest.param("masked", KP, id="masked"),
+    pytest.param("int8", KP, id="int8"),
+    pytest.param("plain", KP2, id="plain-kp533"),
+    pytest.param("int8", KP2, id="int8-kp533"),
+])
+def test_flat_scan(compile_v5e, variant, kp):
+    """The flat scan kernel at what the flat search asks of it: k' plus the
+    refine pad candidates (141 in stage 1, 541 on escalation)."""
     dtype = jnp.int8 if variant == "int8" else F32
     extra = {"masked": "mask", "int8": "scales"}.get(variant)
 
     def fn(corpus, sq, queries, per_row):
         kw = {extra: per_row} if extra else {}
-        return fused_score_topk.score_topk(corpus, sq, queries, KK,
+        return fused_score_topk.score_topk(corpus, sq, queries,
+                                           kp + flat_mod.REFINE_PAD,
                                            interpret=False, **kw)
 
     compile_v5e(fn, ((N, D), dtype), ((N,), F32), ((B, D), F32), ((N,), F32))
@@ -99,15 +111,24 @@ def _rerank(cand, pv, pf, queries):
                                use_pallas=True)
 
 
-def test_flat_search_rerank(compile_v5e):
-    """Flat candidate generation + re-rank, as the engine step runs them."""
+def _compile_flat_search_rerank(compile_v5e, kp):
     def fn(corpus, sq, pv, pf, queries):
         index = flat_mod.FlatIndex(vectors=corpus, sq_norms=sq)
-        _, cand = flat_mod.search(index, queries, KP, use_pallas=True)
+        _, cand = flat_mod.search(index, queries, kp, use_pallas=True)
         return _rerank(cand, pv, pf, queries)
 
     compile_v5e(fn, ((N, D), F32), ((N,), F32), ((N, D), F32), ((N, M), F32),
                 ((B, D), F32))
+
+
+def test_flat_search_rerank(compile_v5e):
+    """Flat candidate generation + re-rank, as the engine step runs them."""
+    _compile_flat_search_rerank(compile_v5e, KP)
+
+
+def test_flat_search_rerank_escalation(compile_v5e):
+    """The same at the escalation's k', on the stage-2 sub-batch."""
+    _compile_flat_search_rerank(compile_v5e, KP2)
 
 
 def _ivf_index(grouped, gsq, valid, centroids, lists, vectors, sq):
@@ -118,9 +139,8 @@ def _ivf_index(grouped, gsq, valid, centroids, lists, vectors, sq):
 
 
 # the benchmark's IVF deployment: SIFT1M widths and its longest list (one
-# page per list), at both k' of the escalating engine: stage 1 and stage 2
-# (c = 8 x kprime_escalation 4)
-CELL_D, CELL_MAX_LIST, KP2 = 128, 2592, 533
+# page per list), at both k' of the escalating engine
+CELL_D, CELL_MAX_LIST = 128, 2592
 # one shard of the IVF engine on a 2x2 mesh (serve/sharded.py): its 256
 # lists plus the all-invalid sentinel slot every non-local probe goes to
 SHARD_SLOTS = NLIST // 4 + 1
