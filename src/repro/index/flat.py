@@ -12,9 +12,8 @@ Two candidate-generation paths, selected by ``use_pallas``:
     streams the corpus in row blocks with a running (value, index) top-k merge
     so the working set stays constant in N.
   * Pallas: ``repro.kernels.ops.score_topk``, the fused distance + running
-    top-k kernel (corpus and queries are zero-padded to the kernel's tile
-    multiples; padded corpus rows carry +inf squared norms so they score
-    -inf and never surface).
+    top-k kernel (queries are zero-padded to the kernel's query tile; the
+    corpus is read in place, a ragged last row block included).
 
 Both paths over-retrieve ``k + REFINE_PAD`` candidates and finish with an
 exact refinement: the matmul expansion ||q||^2 - 2<q,x> + ||x||^2 loses
@@ -155,7 +154,8 @@ def _exact_refine(vectors: Array, queries: Array, cand_idx: Array, k: int,
 
 def _pallas_candidates(index: FlatIndex, queries: Array, kk: int,
                        block_rows: int = 128, block_q: int = 64) -> Array:
-    """Candidate ids via the fused Pallas kernel (padding handled by ops)."""
+    """Candidate ids via the fused Pallas kernel (query padding handled by
+    ops; the corpus is scanned in place)."""
     _, idx = ops.score_topk_padded(index.vectors, index.sq_norms, queries, kk,
                                    block_rows=block_rows, block_q=block_q,
                                    scales=index.scales)

@@ -131,10 +131,12 @@ def _block_scores(x_ref, xsq_ref, scale_ref, mask_ref, q_ref, qsq_ref):
 
 
 def _scan_kernel(*refs, k: int, block_rows: int, has_scale: bool,
-                 has_mask: bool):
+                 has_mask: bool, n_rows: int | None):
     """Plain / int8-scaled / masked / masked+scaled scan: ineligible rows
     (mask 0) score -inf inside the scan — the filter algebra's in-kernel
-    mask plan."""
+    mask plan. ``n_rows`` is the corpus row count when it is no multiple of
+    ``block_rows`` (None otherwise): the last block then runs past the
+    corpus, and its out-of-range columns score -inf."""
     refs = list(refs)
     x_ref, xsq_ref = refs.pop(0), refs.pop(0)
     scale_ref = refs.pop(0) if has_scale else None
@@ -148,6 +150,12 @@ def _scan_kernel(*refs, k: int, block_rows: int, has_scale: bool,
 
     scores = _block_scores(x_ref, xsq_ref, scale_ref, mask_ref, q_ref,
                            qsq_ref)
+    if n_rows is not None:
+        # the boundary block's rows past n hold unspecified values (NaN
+        # included); each score column depends on its own row alone, so
+        # masking those columns leaves the real rows' scores as they are
+        col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        scores = jnp.where(j * block_rows + col < n_rows, scores, NEG_INF)
     merge_topk(run_v_ref, run_i_ref, scores, j * block_rows, k)
 
     @pl.when(j == pl.num_programs(1) - 1)
@@ -159,9 +167,8 @@ def _scan_kernel(*refs, k: int, block_rows: int, has_scale: bool,
 def _check_tiling(n, nq, k, block_rows, block_q):
     block_rows = min(block_rows, n)
     block_q = min(block_q, nq)
-    if n % block_rows or nq % block_q:
-        raise ValueError(
-            f"shapes must tile: n={n} %% {block_rows}, q={nq} %% {block_q}")
+    if nq % block_q:
+        raise ValueError(f"q={nq} queries are no multiple of {block_q}")
     if k > n:
         raise ValueError(f"k={k} > corpus size {n}")
     return block_rows, block_q
@@ -180,6 +187,8 @@ def score_topk(corpus, sq_norms, queries, k: int, *, scales=None, mask=None,
     """corpus: (n, d); sq_norms: (n,); queries: (q, d).
 
     Returns (scores (q, k), ids (q, k)) — negative squared L2, descending.
+    ``n`` need not be a multiple of ``block_rows``: the grid's last corpus
+    block is then ragged and read in place, with no padded copy.
     ``scales`` (n,) routes to the int8 kernel variant (per-row dequant of the
     matmul output; scores are exact for the dequantized rows). ``mask`` (n,)
     float 0/1 routes to the filtered variants: rows at 0 score -inf inside
@@ -188,7 +197,7 @@ def score_topk(corpus, sq_norms, queries, k: int, *, scales=None, mask=None,
     n, d = corpus.shape
     nq = queries.shape[0]
     block_rows, block_q = _check_tiling(n, nq, k, block_rows, block_q)
-    grid = (nq // block_q, n // block_rows)
+    grid = (nq // block_q, pl.cdiv(n, block_rows))
     qsq = jnp.sum(queries.astype(jnp.float32) ** 2, axis=-1, keepdims=True)
 
     row_spec = pl.BlockSpec((block_rows, d), lambda i, j: (j, 0))
@@ -205,7 +214,8 @@ def score_topk(corpus, sq_norms, queries, k: int, *, scales=None, mask=None,
     out_spec = pl.BlockSpec((block_q, k), lambda i, j: (i, 0))
     kernel = functools.partial(_scan_kernel, k=k, block_rows=block_rows,
                                has_scale=scales is not None,
-                               has_mask=mask is not None)
+                               has_mask=mask is not None,
+                               n_rows=n if n % block_rows else None)
     kw = lane_width(k)
     tile = block_q * (kw + block_rows) * 4
     vmem = (2 * block_rows * d * corpus.dtype.itemsize

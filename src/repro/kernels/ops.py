@@ -87,49 +87,27 @@ def score_topk(corpus, sq_norms, queries, k, *, scales=None, mask=None,
                        interpret=_interpret())
 
 
-def _pad_corpus(corpus, sq_norms, scales, queries, br, bq, mask=None):
-    """Zero-pad corpus rows (+inf squared norms, unit scales, zero mask) and
-    queries to tile multiples; pad rows score -inf and never surface."""
-    n, d = corpus.shape
-    nq = queries.shape[0]
-    n_pad = -n % br
-    q_pad = -nq % bq
-    if n_pad:
-        corpus = jnp.concatenate(
-            [corpus, jnp.zeros((n_pad, d), corpus.dtype)], axis=0)
-        sq_norms = jnp.concatenate(
-            [sq_norms, jnp.full((n_pad,), jnp.inf, sq_norms.dtype)])
-        if scales is not None:
-            scales = jnp.concatenate(
-                [scales, jnp.ones((n_pad,), scales.dtype)])
-        if mask is not None:
-            mask = jnp.concatenate(
-                [mask, jnp.zeros((n_pad,), mask.dtype)])
-    if q_pad:
-        queries = jnp.concatenate(
-            [queries, jnp.zeros((q_pad, d), queries.dtype)], axis=0)
-    return corpus, sq_norms, scales, queries, mask
-
-
 def score_topk_padded(corpus, sq_norms, queries, k, *, scales=None, mask=None,
                       use_pallas: bool = True, block_rows: int = 128,
                       block_q: int = 64):
-    """``score_topk`` for arbitrary shapes: zero-pads corpus rows (with +inf
-    squared norms, so pad rows score -inf and never surface) and queries to
-    the kernel's tile multiples, then slices the padding back off. This is
-    the dispatch used by flat candidate generation AND the IVF coarse
-    quantizer (centroid scoring is just a small score_topk). ``mask`` (n,)
-    float 0/1 routes to the filtered kernel variants (ineligible rows score
-    -inf inside the scan); pad rows get mask 0."""
+    """``score_topk`` for arbitrary shapes: zero-pads the queries to the
+    kernel's query tile and slices the padding back off; the corpus goes in
+    as it is (the kernel reads a ragged last row block in place, so no scan
+    copies the slab). This is the dispatch used by flat candidate generation
+    AND the IVF coarse quantizer (centroid scoring is just a small
+    score_topk). ``mask`` (n,) float 0/1 routes to the filtered kernel
+    variants (ineligible rows score -inf inside the scan)."""
     if not use_pallas:
         return ref.ref_score_topk(corpus, sq_norms, queries, k, scales=scales,
                                   mask=mask)
-    n = corpus.shape[0]
+    n, d = corpus.shape
     nq = queries.shape[0]
     br = min(block_rows, n)
     bq = min(block_q, nq)
-    corpus, sq_norms, scales, queries, mask = _pad_corpus(
-        corpus, sq_norms, scales, queries, br, bq, mask)
+    q_pad = -nq % bq
+    if q_pad:
+        queries = jnp.concatenate(
+            [queries, jnp.zeros((q_pad, d), queries.dtype)], axis=0)
     vals, idx = _score_topk(corpus, sq_norms, queries, k, scales=scales,
                             mask=mask, block_rows=br, block_q=bq,
                             interpret=_interpret())

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import fused_score_topk, ops, ref
 
 
 def _rand(r, shape, dtype):
@@ -268,17 +268,90 @@ def test_ivf_dedup_count_steps(b, nlist, maxl, d, nprobe, k):
         ops.ivf_score_topk_dedup(*args, count_steps=True, use_pallas=False)
 
 
-def test_score_topk_padded_arbitrary_shapes():
-    """Padded dispatch: corpus rows and query counts off the tile multiples."""
-    r = np.random.default_rng(3)
-    corpus = _rand(r, (100, 32), jnp.float32)
-    queries = _rand(r, (5, 32), jnp.float32)
-    sq = jnp.sum(corpus * corpus, -1)
-    v1, i1 = ops.score_topk_padded(corpus, sq, queries, 7)
-    v2, i2 = ref.ref_score_topk(corpus, sq, queries, 7)
+def _explicit_pad_score_topk(corpus, sq, queries, k, scales, mask):
+    """Reference: the kernel on explicitly padded operands. Corpus rows
+    zero-padded to the 128-row tile with +inf squared norms, unit scales and
+    a zero mask (pad rows score -inf), queries to the 64-row tile; the
+    query padding is sliced off."""
+    n, d = corpus.shape
+    nq = queries.shape[0]
+    br, bq = min(128, n), min(64, nq)
+    n_pad, q_pad = -n % br, -nq % bq
+    corpus = jnp.concatenate([corpus, jnp.zeros((n_pad, d), corpus.dtype)])
+    sq = jnp.concatenate([sq, jnp.full((n_pad,), jnp.inf, sq.dtype)])
+    if scales is not None:
+        scales = jnp.concatenate([scales, jnp.ones((n_pad,), scales.dtype)])
+    if mask is not None:
+        mask = jnp.concatenate([mask, jnp.zeros((n_pad,), mask.dtype)])
+    queries = jnp.concatenate([queries, jnp.zeros((q_pad, d), queries.dtype)])
+    vals, idx = fused_score_topk.score_topk(
+        corpus, sq, queries, k, scales=scales, mask=mask, block_rows=br,
+        block_q=bq, interpret=True)
+    return vals[:nq], idx[:nq]
+
+
+@pytest.mark.parametrize("n,d,nq,k,variant", [
+    # below one tile: a single block of all n rows, random data
+    pytest.param(100, 32, 5, 7, "random", id="100-below-tile"),
+    # 1000 rows: a ragged last block of 104 rows, k' above it
+    pytest.param(1000, 32, 64, 141, "plain", id="1000-plain"),
+    pytest.param(1000, 32, 64, 141, "masked", id="1000-masked"),
+    pytest.param(1000, 32, 64, 141, "int8", id="1000-int8"),
+    # 20 eligible rows for k = 40, some in the last block: -inf / id-0 fill
+    pytest.param(1000, 32, 64, 40, "mask-short", id="1000-mask-short"),
+    # the cell's CPU twin's row count: a last block of 9 rows; 70 queries
+    pytest.param(15625, 32, 70, 141, "plain", id="15625-plain"),
+    pytest.param(15625, 32, 70, 141, "masked-int8", id="15625-masked-int8"),
+])
+def test_score_topk_padded_arbitrary_shapes(n, d, nq, k, variant):
+    """Padded dispatch: corpus rows and query counts off the tile multiples.
+    The corpus is scanned in place with a ragged last block; ids and scores
+    equal the explicitly padded scan's bit for bit, and the ids equal the
+    plain reference's. Except in the random case, queries are planted on
+    the corpus's last rows so the ragged block's ids must surface."""
+    r = np.random.default_rng(n + k)
+    scales = mask = None
+    if variant == "random":
+        corpus = rows = _rand(r, (n, d), jnp.float32)
+        queries = _rand(r, (nq, d), jnp.float32)
+    else:
+        corpus = rows = _ints(r, (n, d), jnp.float32, -1, 1)
+        if "int8" in variant:
+            # power-of-two scales: every dequantized score stays exact
+            scales = jnp.asarray(2.0 ** r.integers(-1, 2, size=n), jnp.float32)
+            corpus = corpus.astype(jnp.int8)
+            rows = corpus.astype(jnp.float32) * scales[:, None]
+        planted = n - 1 - np.arange(nq) % 64
+        queries = rows[planted]
+        if "mask" in variant:
+            keep = r.random(n) < 0.5
+            if variant == "mask-short":
+                keep = np.zeros(n, bool)
+                keep[r.choice(n - 64, 10, replace=False)] = True
+                keep[n - 10:] = True
+            else:
+                keep[planted] = True
+            mask = jnp.asarray(keep, jnp.float32)
+    sq = jnp.sum(rows * rows, -1)
+    v1, i1 = ops.score_topk_padded(corpus, sq, queries, k, scales=scales,
+                                   mask=mask)
+    v0, i0 = _explicit_pad_score_topk(corpus, sq, queries, k, scales, mask)
+    np.testing.assert_array_equal(np.asarray(v1), np.asarray(v0))
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i0))
+    v2, i2 = ref.ref_score_topk(rows if scales is None else corpus, sq,
+                                queries, k, scales=scales, mask=mask)
     np.testing.assert_allclose(np.asarray(v1), np.asarray(v2),
                                rtol=1e-4, atol=1e-4)
-    assert (np.asarray(i1) == np.asarray(i2)).all()
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+    if variant == "random":
+        return
+    ids = np.asarray(i1)
+    if variant == "mask-short":
+        assert np.isneginf(np.asarray(v1)[:, 20:]).all()
+        assert (ids[:, 20:] == 0).all()
+        assert np.isin(np.arange(n - 10, n), ids).all()
+    else:
+        assert (ids == planted[:, None]).any(axis=1).all()
 
 
 @pytest.mark.parametrize("n,M,ksub,q", [(500, 4, 32, 3), (512, 8, 64, 5)])

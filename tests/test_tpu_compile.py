@@ -7,8 +7,10 @@ the engine step's scan + re-rank around it) at a deployment width — n = 2^20
 rows, d = 768, batch 64, k' = 133 and the escalation's 533 (+ the refine
 pad), m = 8 filter columns, IVF nlist = 1024 with 2048-row lists and
 nprobe = 32 — for one chip of a described ``v5e:2x2`` topology and checks
-that the Mosaic kernel is in the compiled program. Nothing runs and nothing
-needs a chip.
+that the Mosaic kernel is in the compiled program. The flat scan compiles
+also at the benchmark cell's n = 1,000,000, no multiple of its row tile,
+where it must read the slab in place. Nothing runs and nothing needs a
+chip.
 
 The topology is described inside a module fixture (never at import), and
 the persistent compilation cache is off around the compiles: a compile for
@@ -28,6 +30,9 @@ from repro.kernels import fcvi_transform, fused_score_topk, ivf_score
 from repro.kernels import ops, pq_lut, rescore
 
 N, D, B, M = 1 << 20, 768, 64, 8
+# the flat benchmark cell's row count: no multiple of the scan's 128-row
+# tile, so the scan's last corpus block is ragged
+CELL_N = 1_000_000
 KP = 133                       # k' of k=10, lam=0.6, c=8 (theory.k_prime)
 # the escalation's k' (c = 8 x kprime_escalation 4): the engine's stage 2
 # re-runs a batch's ambiguous queries through the same scan at this k'
@@ -64,17 +69,18 @@ def no_compile_cache(topo):
 
 @pytest.fixture
 def compile_v5e(one_chip, no_compile_cache, monkeypatch):
-    """compile_v5e(fn, *shapes) -> compiled text; shapes are (shape, dtype)
-    pairs placed on one described chip. The ops layer is steered to the
-    compiled (non-interpret) kernels, as it is on a TPU backend."""
+    """compile_v5e(fn, *shapes) -> the compiled program; shapes are
+    (shape, dtype) pairs placed on one described chip. The ops layer is
+    steered to the compiled (non-interpret) kernels, as it is on a TPU
+    backend."""
     monkeypatch.setattr(ops, "_interpret", lambda: False)
 
     def go(fn, *shapes):
         args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
                 for s, dt in shapes]
-        text = jax.jit(fn).lower(*args).compile().as_text()
-        assert "tpu_custom_call" in text
-        return text
+        compiled = jax.jit(fn).lower(*args).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        return compiled
 
     return go
 
@@ -82,16 +88,30 @@ def compile_v5e(one_chip, no_compile_cache, monkeypatch):
 F32, I32 = jnp.float32, jnp.int32
 
 
-@pytest.mark.parametrize("variant,kp", [
-    pytest.param("plain", KP, id="plain"),
-    pytest.param("masked", KP, id="masked"),
-    pytest.param("int8", KP, id="int8"),
-    pytest.param("plain", KP2, id="plain-kp533"),
-    pytest.param("int8", KP2, id="int8-kp533"),
+def _assert_scans_in_place(compiled, n, gathered_rows=0):
+    """The scan reads the (n, D) slab where it lies: no copy of it padded
+    to the 128-row tile, and temporaries far below one slab (3.07 GB at the
+    cell's n): 64 MiB, plus the fp32 rows a re-rank gathers."""
+    padded = n + -n % fused_score_topk.DEF_BLOCK_ROWS
+    if padded != n:
+        assert f"[{padded},{D}]" not in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 64 * 2**20 + gathered_rows * D * 4
+
+
+@pytest.mark.parametrize("variant,kp,n", [
+    pytest.param("plain", KP, N, id="plain"),
+    pytest.param("masked", KP, N, id="masked"),
+    pytest.param("int8", KP, N, id="int8"),
+    pytest.param("plain", KP2, N, id="plain-kp533"),
+    pytest.param("int8", KP2, N, id="int8-kp533"),
+    pytest.param("plain", KP, CELL_N, id="cell-plain"),
+    pytest.param("plain", KP2, CELL_N, id="cell-plain-kp533"),
 ])
-def test_flat_scan(compile_v5e, variant, kp):
+def test_flat_scan(compile_v5e, variant, kp, n):
     """The flat scan kernel at what the flat search asks of it: k' plus the
-    refine pad candidates (141 in stage 1, 541 on escalation)."""
+    refine pad candidates (141 in stage 1, 541 on escalation), over 2^20
+    rows and over the cell's 1,000,000 (a ragged last block)."""
     dtype = jnp.int8 if variant == "int8" else F32
     extra = {"masked": "mask", "int8": "scales"}.get(variant)
 
@@ -101,7 +121,9 @@ def test_flat_scan(compile_v5e, variant, kp):
                                            kp + flat_mod.REFINE_PAD,
                                            interpret=False, **kw)
 
-    compile_v5e(fn, ((N, D), dtype), ((N,), F32), ((B, D), F32), ((N,), F32))
+    compiled = compile_v5e(fn, ((n, D), dtype), ((n,), F32), ((B, D), F32),
+                           ((n,), F32))
+    _assert_scans_in_place(compiled, n)
 
 
 def _rerank(cand, pv, pf, queries):
@@ -111,24 +133,33 @@ def _rerank(cand, pv, pf, queries):
                                use_pallas=True)
 
 
-def _compile_flat_search_rerank(compile_v5e, kp):
+def _compile_flat_search_rerank(compile_v5e, kp, n):
     def fn(corpus, sq, pv, pf, queries):
         index = flat_mod.FlatIndex(vectors=corpus, sq_norms=sq)
         _, cand = flat_mod.search(index, queries, kp, use_pallas=True)
         return _rerank(cand, pv, pf, queries)
 
-    compile_v5e(fn, ((N, D), F32), ((N,), F32), ((N, D), F32), ((N, M), F32),
-                ((B, D), F32))
+    compiled = compile_v5e(fn, ((n, D), F32), ((n,), F32), ((n, D), F32),
+                           ((n, M), F32), ((B, D), F32))
+    # the exact refine's and the re-rank's gathers of each query's rows
+    _assert_scans_in_place(compiled, n,
+                           2 * B * (kp + flat_mod.REFINE_PAD))
 
 
-def test_flat_search_rerank(compile_v5e):
-    """Flat candidate generation + re-rank, as the engine step runs them."""
-    _compile_flat_search_rerank(compile_v5e, KP)
+ROWS = [pytest.param(N, id="2pow20"), pytest.param(CELL_N, id="cell")]
 
 
-def test_flat_search_rerank_escalation(compile_v5e):
+@pytest.mark.parametrize("n", ROWS)
+def test_flat_search_rerank(compile_v5e, n):
+    """Flat candidate generation + re-rank, as the engine step runs them,
+    over 2^20 rows and over the cell's 1,000,000."""
+    _compile_flat_search_rerank(compile_v5e, KP, n)
+
+
+@pytest.mark.parametrize("n", ROWS)
+def test_flat_search_rerank_escalation(compile_v5e, n):
     """The same at the escalation's k', on the stage-2 sub-batch."""
-    _compile_flat_search_rerank(compile_v5e, KP2)
+    _compile_flat_search_rerank(compile_v5e, KP2, n)
 
 
 def _ivf_index(grouped, gsq, valid, centroids, lists, vectors, sq):
